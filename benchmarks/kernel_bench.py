@@ -1,6 +1,7 @@
-"""Kernel micro-benchmarks: wall time of the pure-jnp reference path on CPU
-(the Pallas path targets TPU; interpret mode is a correctness tool, not a
-performance path) + HLO-derived TPU roofline estimates per kernel.
+"""Kernel micro-benchmarks: wall time of the pure-jnp reference path on
+the default device (the Pallas path targets TPU; interpret mode is a
+correctness tool, not a performance path), plus an HLO-derived roofline
+bound per kernel when that device is a TPU with known peaks.
 
 The batch-axis sweep measures what same-function invocation batching
 (docs/compute.md) buys at the kernel level: n concurrent invocations of
@@ -17,7 +18,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import Row
 from repro.analysis.hlo_analysis import analyze_hlo_text
-from repro.analysis.roofline import HBM_BW, PEAK_FLOPS
+from repro.analysis.roofline import peaks_for
 
 BATCH_SWEEP = (1, 2, 4, 8)
 
@@ -28,6 +29,17 @@ def _time(fn, *args, iters=3):
     for _ in range(iters):
         jax.block_until_ready(fn(*args))
     return (time.perf_counter() - t0) / iters
+
+
+def _roofline_note(rep) -> str:
+    """The HLO's roofline bound on this chip; nothing off a TPU, where the
+    HLO and the timings are another backend's."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return ""
+    p = peaks_for(dev.device_kind)
+    est = max(rep.dot_flops / p.flops, rep.hbm_bytes / p.hbm_bw)
+    return f" tpu_roofline_est={est * 1e6:.1f}us"
 
 
 def batch_sweep(quick: bool = True):
@@ -97,9 +109,8 @@ def run(quick: bool = True):
     t = _time(f, q, k, v)
     lowered = f.lower(q, k, v).compile()
     rep = analyze_hlo_text(lowered.as_text())
-    tpu_est = max(rep.dot_flops / PEAK_FLOPS, rep.hbm_bytes / HBM_BW)
     rows.append(Row("kernel_flash_attention_2k", t * 1e6,
-                    f"flops={rep.dot_flops:.2e} tpu_roofline_est={tpu_est*1e6:.1f}us"))
+                    f"flops={rep.dot_flops:.2e}{_roofline_note(rep)}"))
 
     from repro.models.mamba2 import ssd_chunked_ref
 
@@ -113,9 +124,8 @@ def run(quick: bool = True):
     g = jax.jit(lambda *a: ssd_chunked_ref(*a, chunk=128)[0])
     t = _time(g, x, dt, A, Bm, Cm)
     rep = analyze_hlo_text(g.lower(x, dt, A, Bm, Cm).compile().as_text())
-    tpu_est = max(rep.dot_flops / PEAK_FLOPS, rep.hbm_bytes / HBM_BW)
     rows.append(Row("kernel_ssd_scan_2k", t * 1e6,
-                    f"flops={rep.dot_flops:.2e} tpu_roofline_est={tpu_est*1e6:.1f}us"))
+                    f"flops={rep.dot_flops:.2e}{_roofline_note(rep)}"))
 
     from repro.models.layers import decode_attention_ref
 
@@ -128,9 +138,8 @@ def run(quick: bool = True):
     h = jax.jit(lambda *a: decode_attention_ref(*a))
     t = _time(h, q, kc, vc, lens)
     rep = analyze_hlo_text(h.lower(q, kc, vc, lens).compile().as_text())
-    tpu_est = max(rep.dot_flops / PEAK_FLOPS, rep.hbm_bytes / HBM_BW)
     rows.append(Row("kernel_decode_attention_8k", t * 1e6,
-                    f"hbm={rep.hbm_bytes:.2e}B tpu_roofline_est={tpu_est*1e6:.1f}us"))
+                    f"hbm={rep.hbm_bytes:.2e}B{_roofline_note(rep)}"))
     rows.extend(batch_sweep(quick))
     return rows
 

@@ -23,6 +23,8 @@ import sys
 import time
 from typing import Dict, List, Tuple
 
+import numpy as np
+
 from benchmarks.common import Row, data_plane_function
 from repro.api import FunctionSpec, Gateway
 from repro.api.workload import Arrival, DiurnalWorkload
@@ -151,7 +153,7 @@ def run_runtime(planned: bool, quick: bool = False,
     clk = cluster.nodes[0].clock
     t0 = clk.now()
     for name in names:
-        db.put(f"{name}/weights", b"W", size=ro_mb * MB)
+        db.put(f"{name}/weights", np.zeros(1, np.uint8), size=ro_mb * MB)
         cluster.register_function(
             lambda i, name=name: data_plane_function(name))
     events = _diurnal_arrivals(classes, duration, duration, seed)
@@ -163,7 +165,7 @@ def run_runtime(planned: bool, quick: bool = False,
             if lag > 0:
                 time.sleep(lag)
             wkey = f"{a.function}/in/{k}"
-            db.put(wkey, b"X", size=2 * MB)
+            db.put(wkey, np.zeros(1, np.uint8), size=2 * MB)
             req = Request(function_name=a.function)
             req.in_data = [
                 Data(key=f"{a.function}/weights", size=ro_mb * MB,
@@ -247,4 +249,7 @@ def main(quick: bool = False) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main(quick="--quick" in sys.argv))

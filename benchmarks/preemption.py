@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import time
 
+import numpy as np
+
 from benchmarks.common import Row, data_plane_function
 from repro.api import FunctionSpec, Gateway, MixWorkload
 from repro.core.profiles import MB
@@ -73,7 +75,7 @@ def _runtime_stats(transfer: str, rounds: int):
     def req(fn, mb, deadline_s, priority, tag):
         r = Request(function_name=fn)
         key = f"{fn}/in/{tag}"
-        rt.db.put(key, b"X", size=mb * MB)
+        rt.db.put(key, np.zeros(1, np.uint8), size=mb * MB)
         r.in_data = [Data(key=key, size=mb * MB, dtype=DataType.WRITABLE)]
         r.deadline_s, r.priority = deadline_s, priority
         return r
@@ -128,5 +130,8 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         r.print()

@@ -93,6 +93,9 @@ def main() -> None:
                     help="with --bench-json: exit 1 if the headline replay "
                          "falls below this events/s floor")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.bench_json:
         bench_json_main(args)
         return
@@ -129,12 +132,16 @@ def main() -> None:
         modules = {k: v for k, v in modules.items() if k in keep}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, mod in modules.items():
         try:
             for row in mod.run(quick=quick):
                 row.print()
         except Exception as e:  # a failing table must not hide the others
             print(f"{name},-1,ERROR:{type(e).__name__}:{e}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"failed tables: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -13,6 +13,8 @@ from __future__ import annotations
 import time
 from typing import Dict
 
+import numpy as np
+
 from benchmarks.common import NAMES, Row, data_plane_function, replay
 from repro.api import FunctionSpec, Gateway, MAFWorkload, TraceWorkload
 from repro.core.profiles import MB
@@ -91,7 +93,7 @@ def dispatch_comparison_runtime(policy: str, *, n_fns: int = 6,
     cluster.sage_init()
     names = [f"fn{i}" for i in range(n_fns)]
     for name in names:
-        db.put(f"{name}/weights", b"W", size=ro_mb * MB)
+        db.put(f"{name}/weights", np.zeros(1, np.uint8), size=ro_mb * MB)
         cluster.register_function(
             lambda i, name=name: data_plane_function(name))
 
@@ -101,7 +103,7 @@ def dispatch_comparison_runtime(policy: str, *, n_fns: int = 6,
             for name in names:
                 req = Request(function_name=name)
                 wkey = f"{name}/in/{r}"
-                db.put(wkey, b"X", size=2 * MB)
+                db.put(wkey, np.zeros(1, np.uint8), size=2 * MB)
                 req.in_data = [
                     Data(key=f"{name}/weights", size=ro_mb * MB,
                          dtype=DataType.READ_ONLY),
@@ -153,5 +155,8 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         r.print()

@@ -199,4 +199,7 @@ def main(quick: bool = False) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main(quick="--quick" in sys.argv))

@@ -11,6 +11,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.api import FunctionSpec, Gateway
+from repro.launch.cache import enable_compile_cache
 
 TTL = 0.6  # compressed 30 s -> 0.6 s per stage for the demo
 
@@ -21,6 +22,7 @@ def mem(gw):
 
 
 def main():
+    enable_compile_cache()
     gw = Gateway(backend="runtime", policy="sage", time_scale=0.05,
                  exit_ttl=TTL)
     gw.register(FunctionSpec(name="f", arch="qwen2.5-3b", profile="resnet50"))

@@ -16,9 +16,11 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.api import FunctionSpec, Gateway
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # one gateway per node: SageInit + one memory daemon per device
     gw = Gateway(backend="runtime", policy="sage", time_scale=0.2,
                  exit_ttl=30.0)

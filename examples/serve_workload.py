@@ -13,6 +13,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.api import FunctionSpec, Gateway, PoissonWorkload
+from repro.launch.cache import enable_compile_cache
 
 SPECS = [
     FunctionSpec(name="qwen2.5-3b-fn", arch="qwen2.5-3b", profile="resnet50",
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--rate", type=float, default=6.0)
     args = ap.parse_args()
+    enable_compile_cache()
     print("system     load                mean        p99      warm   slo   sharing  memory")
     base = drive("fixedgsl", args.requests, args.rate)
     sage = drive("sage", args.requests, args.rate)

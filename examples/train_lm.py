@@ -15,6 +15,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 
 from repro.configs import get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.launch.train import train_loop
 
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.tiny:
         arch, smoke, gb, seq = "qwen2.5-3b", True, 8, 64

@@ -442,13 +442,8 @@ def analyze_hlo_text(txt: str, num_partitions: Optional[int] = None) -> CostRepo
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()``: older JAX returns a
-    one-dict-per-device list, newer returns the dict directly — callers
-    always get a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 def analyze_compiled(compiled) -> dict:

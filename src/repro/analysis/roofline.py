@@ -1,7 +1,7 @@
 """Roofline terms from a dry-run analysis record.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment constants).
+Hardware model: the peaks of the chip named by its ``device_kind``
+(:data:`PEAKS`); a kind that is not in the table is an error.
 
 All analyzer quantities are *per device* (the SPMD module is the per-device
 program), so:
@@ -13,7 +13,7 @@ program), so:
 MODEL_FLOPS uses the 6*N*D / 2*N*D convention (train / inference) with
 N = active params (MoE-aware), D = tokens per step — the ratio against
 compiled dot-FLOPs exposes remat recompute, causal waste, and dispatch
-overhead (see EXPERIMENTS.md §Roofline).
+overhead.
 """
 from __future__ import annotations
 
@@ -22,9 +22,24 @@ from typing import Dict
 
 from repro.configs.base import ModelConfig
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9       # bytes/s / chip
-LINK_BW = 50e9       # bytes/s / link (ICI)
+@dataclass(frozen=True)
+class Peaks:
+    flops: float    # bf16 FLOP/s per chip
+    hbm_bw: float   # HBM bytes/s per chip
+    link_bw: float  # ICI bytes/s per link
+
+
+# keyed by jax's ``device_kind``. TPU v5e: 197 TFLOP/s bf16 and 819 GB/s of
+# HBM (Google Cloud documentation, "TPU v5e"); 1,600 Gbit/s of ICI per chip
+# over its four links, 50 GB/s each
+PEAKS = {"TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9)}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak figures for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def model_flops(cfg: ModelConfig, mode: str, tokens: int) -> float:
@@ -35,21 +50,23 @@ def model_flops(cfg: ModelConfig, mode: str, tokens: int) -> float:
 
 
 def roofline_from_report(
-    cfg: ModelConfig, report: Dict, *, chips: int, mode: str, tokens: int
+    cfg: ModelConfig, report: Dict, *, chips: int, mode: str, tokens: int,
+    device_kind: str,
 ) -> Dict:
+    peaks = peaks_for(device_kind)
     flops = report["flops"]
     dot_flops = report["dot_flops"]
     hbm = report["hbm_bytes"]
     coll = report["collective_bytes"]
     coll_traffic = report["collective_traffic_bytes"]
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    collective_s = coll / LINK_BW
+    compute_s = flops / peaks.flops
+    memory_s = hbm / peaks.hbm_bw
+    collective_s = coll / peaks.link_bw
     terms = {"compute_s": compute_s, "memory_s": memory_s, "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
     # TPU-fusion-aware memory estimate (elementwise fused away); falls back
     # to the conservative bound for artifacts predating the field
-    memory_fused_s = report.get("hbm_bytes_fused", hbm) / HBM_BW
+    memory_fused_s = report.get("hbm_bytes_fused", hbm) / peaks.hbm_bw
     mf = model_flops(cfg, mode, tokens)
     hlo_global_flops = flops * chips
     return {
@@ -57,7 +74,7 @@ def roofline_from_report(
         "memory_s": memory_s,
         "memory_fused_s": memory_fused_s,
         "collective_s": collective_s,
-        "collective_traffic_s": coll_traffic / LINK_BW,
+        "collective_traffic_s": coll_traffic / peaks.link_bw,
         "dominant": dominant,
         "model_flops_global": mf,
         "hlo_flops_global": hlo_global_flops,
@@ -66,6 +83,6 @@ def roofline_from_report(
         # fraction of the compute roofline actually achieved if the dominant
         # term were the wall clock (MODEL_FLOPS / (chips*peak) / bound)
         "roofline_fraction": (
-            (mf / (chips * PEAK_FLOPS)) / max(max(terms.values()), 1e-30)
+            (mf / (chips * peaks.flops)) / max(max(terms.values()), 1e-30)
         ),
     }

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional, Tuple, Union
 
 from repro.api.spec import FunctionSpec
 from repro.api.workload import Arrival, Workload
+from repro.core.daemon import MODELED_CAPACITY
 from repro.core.dispatch import DISPATCH_POLICIES, choose_node
 from repro.core.faults import (
     BreakerConfig,
@@ -411,7 +412,7 @@ class Gateway:
     """One serving API over the real runtime and the simulator twin."""
 
     def __init__(self, backend: str = "sim", policy: str = "sage", *,
-                 n_nodes: int = 1, device_capacity: int = 40 << 30,
+                 n_nodes: int = 1, device_capacity: Optional[int] = None,
                  host_capacity: int = 125 << 30,
                  exit_ttl: float = 30.0, seed: int = 0,
                  time_scale: float = 1.0, loader_threads: int = 4,
@@ -515,7 +516,9 @@ class Gateway:
             from repro.core.simulator import Simulator
 
             self.sim = Simulator(
-                policy, n_nodes=n_nodes, capacity=device_capacity,
+                policy, n_nodes=n_nodes,
+                capacity=MODELED_CAPACITY if device_capacity is None
+                else device_capacity,
                 host_capacity=host_capacity,
                 exit_ttl=exit_ttl, seed=seed, loader_threads=loader_threads,
                 # backend-native deadline defaults: 600 virtual s (sim)
@@ -578,7 +581,7 @@ class Gateway:
         each node compiles its own context — before it enters
         ``_nodes``/``_fns`` indexing."""
         for name, spec in self.specs.items():
-            fn = spec.to_gpu_function(node.db)
+            fn = spec.to_gpu_function(node.db, node.device)
             node.register_function(fn)
             self._fns[name].append(fn)
         self._nodes.append(node)
@@ -625,8 +628,9 @@ class Gateway:
             self.sim.register(spec.to_sim_function())
         else:
             fns = []
+            params = spec.host_params()  # one host copy for every node
             for node in self._nodes:  # each node compiles its own context
-                fn = spec.to_gpu_function(node.db)
+                fn = spec.to_gpu_function(node.db, node.device, params)
                 node.register_function(fn)
                 fns.append(fn)
             self._fns[spec.name] = fns

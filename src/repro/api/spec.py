@@ -64,6 +64,9 @@ class FunctionSpec:
     batch: int = 1                         # real backend request shape
     seq: int = 16
     seed: int = 0                          # real backend weight init
+    # real backend model size: the arch's published configuration, or
+    # (the default) its reduced preset
+    full_width: bool = False
     # per-function circuit-breaker policy (docs/resilience.md); overrides
     # any gateway-wide ``breaker=`` for this function at register()
     breaker: Optional[object] = None
@@ -165,15 +168,22 @@ class FunctionSpec:
         return SimFunction(self.resolved_profile(), name=self.name,
                            sm_fraction=self.sm_fraction)
 
-    def to_gpu_function(self, db):
-        """Real lowering: compile a reduced ``arch`` model and put its
-        weights in ``db`` (lazy import keeps sim-only users off jax)."""
+    def host_params(self):
+        """The real backend's weights, a host (numpy) pytree from ``seed``."""
+        from repro.core.functions import host_params, model_config
+
+        return host_params(model_config(self.arch, self.full_width), self.seed)
+
+    def to_gpu_function(self, db, device=None, params=None):
+        """Real lowering: an ``arch`` model compiled for ``device`` with its
+        weights (``params``, or built from ``seed``) put in ``db``."""
         from repro.core.functions import make_model_function
 
         fn = make_model_function(
             db, self.name, arch=self.arch, batch=self.batch, seq=self.seq,
             profile=self.base_profile(), declared_ro_bytes=self.read_only_bytes,
-            seed=self.seed,
+            seed=self.seed, full_width=self.full_width, device=device,
+            params=params,
         )
         over: dict = {}
         if self.writable_bytes is not None:

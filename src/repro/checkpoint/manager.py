@@ -29,51 +29,13 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import msgpack
 import numpy as np
-
-try:  # optional: fall back to stdlib zlib when the wheel is absent
-    import zstandard as zstd
-except ImportError:  # pragma: no cover - exercised on zstd-less installs
-    zstd = None
-
-import zlib
-
-
-class _ZlibCompressor:
-    """Stdlib stand-in for ``zstd.ZstdCompressor`` (same duck type)."""
-
-    def __init__(self, level: int = 6):
-        self.level = min(max(level, 1), 9)
-
-    def compress(self, buf: bytes) -> bytes:
-        return zlib.compress(buf, self.level)
-
-
-class _ZlibDecompressor:
-    def decompress(self, blob: bytes, max_output_size: int = 0) -> bytes:
-        return zlib.decompress(blob)
-
-
-def _codec_name() -> str:
-    return "zstd" if zstd is not None else "zlib"
-
-
-def _compressor(level: int):
-    if zstd is not None:
-        return zstd.ZstdCompressor(level=level)
-    return _ZlibCompressor(level)
+import zstandard as zstd
 
 
 def _decompressor(codec: str):
-    if codec == "zstd":
-        if zstd is None:
-            raise IOError(
-                "checkpoint was written with zstd but the 'zstandard' "
-                "package is not installed; pip install zstandard to restore"
-            )
-        return zstd.ZstdDecompressor()
-    if codec == "zlib":
-        return _ZlibDecompressor()
-    raise IOError(f"unknown checkpoint codec {codec!r}")
+    if codec != "zstd":
+        raise IOError(f"unknown checkpoint codec {codec!r}")
+    return zstd.ZstdDecompressor()
 
 
 def _path_str(path) -> str:
@@ -128,7 +90,7 @@ class CheckpointManager:
         tmp = self.dir / f"step_{step:010d}.tmp"
         tmp.mkdir(parents=True, exist_ok=True)
 
-        cctx = _compressor(self.zstd)
+        cctx = zstd.ZstdCompressor(level=self.zstd)
         shard_meta = {}
         payload = {}
         for k in my_keys:
@@ -143,7 +105,7 @@ class CheckpointManager:
             }
         shard_path = tmp / f"shard_{host_id:05d}.msgpack.zst"
         with open(shard_path, "wb") as f:
-            f.write(msgpack.packb({"codec": _codec_name(), "meta": shard_meta,
+            f.write(msgpack.packb({"codec": "zstd", "meta": shard_meta,
                                    "data": payload}, use_bin_type=True))
             f.flush()
             os.fsync(f.fileno())

@@ -43,11 +43,13 @@ holding it run-to-completion. The default ``"run_to_completion"`` drives
 each leg as one full-size advance, reproducing the pre-stream behavior
 bit-for-bit.
 
-TPU adaptation note (DESIGN.md §2): CUDA-IPC cross-process sharing becomes
+TPU adaptation note: CUDA-IPC cross-process sharing becomes
 single-broker buffer-handle sharing — the daemon owns ``jax.Array``s and
-invocations hold references. Capacity accounting uses the declared A100-scale
-sizes (``Data.size``) while payloads are real (reduced) arrays, so the
-admission/eviction logic is exercised truthfully on CPU.
+invocations hold references. Each daemon is bound to one device: a load's
+device leg is a ``jax.device_put`` of the host payload to that device,
+waited on until the bytes are in HBM. Capacity accounting uses the declared
+sizes (``Data.size``); the capacity itself is the device's own HBM limit
+on a TPU and the A100-40GB figure elsewhere (:func:`capacity_of`).
 """
 from __future__ import annotations
 
@@ -60,6 +62,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
 from repro.core.clock import RealClock
 from repro.core.datapath import DataPaths
 from repro.core.request import Data, DataType, Request
@@ -68,6 +72,22 @@ from repro.core.transfer import (
 )
 
 GPU_CONTEXT_BYTES = 414 * 1024 * 1024  # paper §1/§3: 414 MB per GPU context
+MODELED_CAPACITY = 40 << 30  # A100-40GB, the paper's device
+
+
+def capacity_of(device) -> int:
+    """Device bytes the daemon may admit on ``device``: a TPU's own HBM
+    limit, or the A100-40GB capacity the paper's profiles assume on any
+    other backend (the CPU the tests run on)."""
+    if device.platform != "tpu":
+        return MODELED_CAPACITY
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{device} reports no memory_stats()['bytes_limit']; pass "
+            f"device_capacity explicitly")
+    return int(limit)
+
 
 SCHEDULERS = ("fifo", "edf")
 
@@ -305,8 +325,9 @@ class MemoryDaemon:
         paths: DataPaths,
         database,
         *,
-        device_capacity: int = 40 << 30,  # A100-40GB (v5e would be 16 GiB)
+        device_capacity: Optional[int] = None,  # None: capacity_of(device)
         host_capacity: int = 125 << 30,
+        device=None,  # None: jax.devices()[0]
         clock=None,
         loader_threads: int = 4,
         load_timeout_s: float = 30.0,
@@ -324,7 +345,9 @@ class MemoryDaemon:
         self.paths = paths
         self.db = database
         self.clock = clock or RealClock()
-        self.capacity = device_capacity
+        self.device = device if device is not None else jax.devices()[0]
+        self.capacity = (device_capacity if device_capacity is not None
+                         else capacity_of(self.device))
         self.host_capacity = host_capacity
         self.time_scale = time_scale
         self.loader_threads = loader_threads
@@ -1090,7 +1113,10 @@ class MemoryDaemon:
             self._reserve_device_blocking(
                 e.size, time.monotonic() + self.load_timeout_s, entry=e
             )
-            dev = self.db.to_device(e.host_obj)
+            # host -> this daemon's device; the entry turns DEVICE only
+            # once the bytes are in HBM
+            dev = jax.block_until_ready(
+                jax.device_put(e.host_obj, self.device))
         except _LoadCancelled:
             self._abort(e)
             return

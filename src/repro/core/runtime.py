@@ -7,16 +7,18 @@ processes one invocation end-to-end. The same runtime object runs any
 compares systems on identical mechanism code.
 
 This is the *real* threaded runtime: context creation is an actual
-``jax.jit`` compile, data movement is an actual ``device_put`` (with the
-fair-share brokers modeling A100-scale transfer times), compute is the
-actual jitted model. The virtual-time twin for trace-scale experiments is
-``core.simulator``.
+``jax.jit`` compile for the node's device, data movement is an actual
+``device_put`` to that device (with the fair-share brokers modeling
+A100-scale transfer times on top), compute is the actual jitted model. The
+virtual-time twin for trace-scale experiments is ``core.simulator``.
 """
 from __future__ import annotations
 
 import threading
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
+
+import jax
 
 from repro.core.baselines import SystemPolicy, get_system
 from repro.core.clock import RealClock
@@ -42,7 +44,8 @@ class SageRuntime:
         policy: SystemPolicy | str = "sage",
         *,
         database: Optional[Database] = None,
-        device_capacity: int = 40 << 30,
+        device=None,
+        device_capacity: Optional[int] = None,
         host_capacity: int = 125 << 30,
         time_scale: float = 1.0,
         exit_ttl: float = 30.0,
@@ -62,7 +65,9 @@ class SageRuntime:
         self.db = database or Database()
         self.paths = DataPaths.make(self.clock)
         self.daemon = MemoryDaemon(
-            self.paths, self.db, device_capacity=device_capacity,
+            self.paths, self.db, device=device,
+            # None: the device's own HBM limit on a TPU (daemon.capacity_of)
+            device_capacity=device_capacity,
             host_capacity=host_capacity,
             clock=self.clock, time_scale=time_scale,
             loader_threads=loader_threads, load_timeout_s=load_timeout_s,
@@ -80,6 +85,7 @@ class SageRuntime:
             # platforms load per-invocation (ungated), same as the sim twin
             pooled=self.policy.name.startswith("sage"),
         )
+        self.device = self.daemon.device  # contexts compile for this device
         self.executor = KernelExecutor(self.clock)
         self.telemetry = Telemetry()
         self.engines: Dict[str, FunctionEngine] = {}
@@ -358,7 +364,8 @@ class ClusterRuntime:
             raise ValueError(
                 f"unknown dispatch {dispatch!r}; use one of {DISPATCH_POLICIES}")
         self._node_kwargs = dict(node_kwargs)
-        self.nodes = [SageRuntime(node_id=f"gpu{i}", **node_kwargs)
+        self.nodes = [SageRuntime(node_id=f"gpu{i}", device=self._device(i),
+                                  **node_kwargs)
                       for i in range(n_nodes)]
         self._node_seq = n_nodes
         self._rng = random.Random(seed)
@@ -383,6 +390,13 @@ class ClusterRuntime:
         self.health_score = None
         if dispatch == "planned" or self.autoscale is not None:
             self._ensure_control()
+
+    @staticmethod
+    def _device(i: int):
+        """Node ``i`` runs on its own chip where the host has one for it;
+        more nodes than chips (the one CPU device in tests) wrap around."""
+        devs = jax.devices()
+        return devs[i % len(devs)]
 
     def sage_init(self):
         self._initialized = True
@@ -448,6 +462,7 @@ class ClusterRuntime:
         """Provision one cold node: every registered function builder is
         replayed onto it and dispatch may target it immediately."""
         node = SageRuntime(node_id=f"gpu{self._node_seq}",
+                           device=self._device(self._node_seq),
                            **self._node_kwargs)
         self._node_seq += 1
         # a later set_compute carries over to joiners (same contract as

@@ -1,16 +1,15 @@
 """In-memory 'database' (the paper uses MongoDB) with brokered fetch timing.
 
-Values are real Python/JAX objects (reduced-model weight pytrees, inputs);
-fetch latency is modeled through the shared db bandwidth broker using the
-*declared* A100-scale size, so contention behaves like the paper's Fig 4
-while payloads stay CPU-sized.
+Values are host objects: numpy pytrees (model weights, request inputs)
+or small numpy stand-ins for modeled payloads. Nothing here lives on a
+device; the memory daemon's load is what moves a value into HBM. Fetch
+latency is modeled through the shared db bandwidth broker using the
+*declared* size, so contention behaves like the paper's Fig 4.
 """
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, Optional
-
-import jax
+from typing import Any, Dict
 
 
 class Database:
@@ -32,12 +31,3 @@ class Database:
             broker.transfer(self._sizes.get(key, 0), scale=scale)
         with self._lock:
             return self._kv.get(key)
-
-    def to_device(self, obj: Any) -> Any:
-        """Host -> device materialization (jax.device_put for pytrees)."""
-        if obj is None:
-            return None
-        try:
-            return jax.device_put(obj)
-        except TypeError:
-            return obj

@@ -1,3 +1,2 @@
 from repro.distributed import rules  # noqa: F401
 from repro.distributed.act_sharding import activation_policy, constrain  # noqa: F401
-from repro.distributed.compat import abstract_mesh, make_mesh  # noqa: F401

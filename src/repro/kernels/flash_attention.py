@@ -12,9 +12,11 @@ Design (TPU-native, not a CUDA port):
   work issued), giving the exact triangular FLOP count;
 * fp32 accumulation; bf16 (or input dtype) output.
 
-Block sizes default to (512, 512): VMEM for one program =
-q (G*512*128*2B) + k/v (2*512*128*2B) + acc (G*512*128*4B) ~= 1.8 MiB at
-G=8 — comfortably inside the ~16 MiB VMEM budget with double buffering.
+Block sizes default to (256, 512). The fp32 scores of one program are
+(G*block_q, block_k): 4 MiB at G=8, and the (G*block_q, 1) m/l scratch is
+lane-padded to 128, 1 MiB each. At qwen2.5-3b widths (G=8, Dh=128)
+(512, 512) asked for 19.26 MiB of scoped VMEM, over a v5e's 16 MiB limit;
+(256, 512) compiles (tests/test_tpu_compile.py).
 
 Validated in interpret mode against ``repro.models.layers.flash_attention_
 ref`` (itself validated against plain softmax attention) — see
@@ -111,7 +113,7 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = True,
-    block_q: int = 512,
+    block_q: int = 256,
     block_k: int = 512,
     scale: Optional[float] = None,
     interpret: bool = False,

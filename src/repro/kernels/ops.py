@@ -3,8 +3,9 @@
 ``use_pallas='auto'`` selects the Pallas kernel on TPU and the pure-jnp
 reference elsewhere (Pallas does not lower to the CPU host platform; the
 dry-run therefore analyses the reference HLO — conservative for the paths
-we hand-optimize). ``use_pallas=True`` with ``interpret=True`` runs the
-kernel body in Python on CPU — how the tests validate it.
+we hand-optimize). ``use_pallas=True`` demands the compiled kernel and
+raises off a TPU; ``use_pallas='interpret'`` runs the kernel body in Python
+— how the tests validate it. Nothing falls back to another path silently.
 """
 from __future__ import annotations
 
@@ -30,10 +31,15 @@ def _resolve(use_pallas) -> Tuple[bool, bool]:
         return (_on_tpu(), False)
     if use_pallas == "interpret":
         return (True, True)
-    return (bool(use_pallas), not _on_tpu())
+    if use_pallas and not _on_tpu():
+        raise RuntimeError(
+            f"use_pallas=True needs a TPU backend, not "
+            f"{jax.default_backend()!r}; ask for use_pallas='interpret' to "
+            f"run the kernel body off the chip")
+    return (bool(use_pallas), False)
 
 
-def flash_attention(q, k, v, *, causal=True, block_q=512, block_k=512,
+def flash_attention(q, k, v, *, causal=True, block_q=256, block_k=512,
                     use_pallas="auto"):
     use, interp = _resolve(use_pallas)
     if use:
@@ -58,7 +64,11 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_k=1024,
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk=128, initial_state=None,
              use_pallas="auto"):
     use, interp = _resolve(use_pallas)
-    if use and initial_state is None:
+    if use:
+        if initial_state is not None:
+            raise ValueError(
+                "the Pallas ssd_scan starts from a zero state; pass "
+                "use_pallas=False to run the reference from initial_state")
         return _ssd.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=interp)
     return _ref.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk,
                          initial_state=initial_state)

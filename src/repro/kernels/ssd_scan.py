@@ -25,20 +25,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _segsum(x: jax.Array) -> jax.Array:
-    """x: (T,) -> (T, T) lower-tri segment sums, -inf above diagonal."""
-    T = x.shape[-1]
-    cs = jnp.cumsum(x)
-    diff = cs[:, None] - cs[None, :]
+def _cumsums(d_col: jax.Array, d_row: jax.Array):
+    """Inclusive cumsum of one (T,) vector given as a (T, 1) column and a
+    (1, T) row; returns it in both layouts, plus the lower-triangle mask.
+    Masked 2-D reductions keep every value 2-D in the TPU's native layout:
+    no 1-D vector, ``cumsum`` or transpose inside the kernel."""
+    T = d_col.shape[0]
     ii = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    return jnp.where(ii >= jj, diff, -jnp.inf)
+    zero = jnp.zeros((T, T), jnp.float32)
+    cs_col = jnp.where(jj <= ii, d_row + zero, zero).sum(axis=1, keepdims=True)
+    cs_row = jnp.where(ii <= jj, d_col + zero, zero).sum(axis=0, keepdims=True)
+    return cs_col, cs_row, ii >= jj
 
 
 def _ssd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref,  # inputs
-    y_ref, fs_ref,                       # outputs: y, final state
-    state_scr,                           # VMEM scratch: (P, N) fp32
+    x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref,  # inputs
+    y_ref, fs_ref,                                 # outputs: y, final state
+    state_scr,                                     # VMEM scratch: (P, N) fp32
     *,
     chunk: int,
 ):
@@ -50,15 +54,18 @@ def _ssd_kernel(
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0, 0].astype(jnp.float32)        # (chunk, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)   # (chunk,)
-    A = a_ref[0]                               # scalar for this head
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)  # (chunk, 1)
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)  # (1, chunk)
+    A = a_ref[h]                               # scalar for this head (SMEM)
     Bm = b_ref[0].astype(jnp.float32)          # (chunk, N)
     Cm = c_ref[0].astype(jnp.float32)          # (chunk, N)
 
-    xdt = x * dt[:, None]
-    dA = dt * A                                # (chunk,)
-    dA_cs = jnp.cumsum(dA)                     # inclusive
-    L = jnp.exp(_segsum(dA))                   # (chunk, chunk)
+    xdt = x * dt_col
+    # inclusive cumsum of dA = dt * A; L = exp(segsum(dA)), 0 above the
+    # diagonal
+    dA_cs, dA_cs_row, lower = _cumsums(dt_col * A, dt_row * A)
+    L = jnp.where(lower, jnp.exp(dA_cs - dA_cs_row), 0.0)  # (chunk, chunk)
+    dA_sum = (dt_col * A).sum(axis=0, keepdims=True)       # (1, 1)
 
     CB = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -70,16 +77,16 @@ def _ssd_kernel(
     state_in = state_scr[...]                  # (P, N)
     y_off = jax.lax.dot_general(
         Cm, state_in, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * jnp.exp(dA_cs)[:, None]                # (chunk, P)
+    ) * jnp.exp(dA_cs)                         # (chunk, P)
 
     y_ref[...] = (y_diag + y_off).reshape(y_ref.shape).astype(y_ref.dtype)
 
-    decay_states = jnp.exp(dA_cs[-1] - dA_cs)  # (chunk,)
+    decay_states = jnp.exp(dA_sum - dA_cs)     # (chunk, 1)
     upd = jax.lax.dot_general(
-        xdt, Bm * decay_states[:, None], (((0,), (0,)), ((), ())),
+        xdt, Bm * decay_states, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                          # (P, N)
-    state_scr[...] = state_in * jnp.exp(dA_cs[-1]) + upd
+    state_scr[...] = state_in * jnp.exp(dA_sum) + upd
 
     @pl.when(ci == n_c - 1)
     def _fin():
@@ -109,6 +116,9 @@ def ssd_scan(
     Sp = S + pad
     n_c = Sp // chunk
     xt = x.transpose(0, 2, 1, 3)  # (B,H,S,P)
+    # dt per head as a column (B,H,S,1) and a row (B,H,1,S): both blocks
+    # keep the TPU's (8, 128) tiling on their last two dims
+    dtt = dt.transpose(0, 2, 1)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y, fs = pl.pallas_call(
@@ -116,8 +126,9 @@ def ssd_scan(
         grid=(Bsz, H, n_c),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # A: (H,) scalars
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
@@ -134,6 +145,6 @@ def ssd_scan(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(xt, dt, A.astype(jnp.float32), Bm, Cm)
+    )(xt, dtt[..., None], dtt[:, :, None, :], A.astype(jnp.float32), Bm, Cm)
     y = y.transpose(0, 2, 1, 3)[:, :S]
     return y.astype(x.dtype), fs

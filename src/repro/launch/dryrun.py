@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 """Multi-pod dry-run: AOT lower + compile every (arch x shape) cell on the
 production mesh, print memory/cost analysis, and emit the roofline terms.
 
-The two lines above MUST stay first: jax locks the device count on first
-init, and the dry-run needs 512 placeholder host devices for the 2x16x16
-multi-pod mesh. Nothing else in the repo sets this flag.
+The three lines above MUST stay first: jax locks the platform and the
+device count on first init, and the dry-run needs 512 placeholder host
+devices for the 2x16x16 multi-pod mesh. Nothing else in the repo sets this
+flag. It is a CPU analysis tool: pinned to the CPU platform, it and the
+cell processes it starts (which inherit the environment) never take a TPU
+from another process.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k [--multi-pod]
@@ -26,6 +30,8 @@ from pathlib import Path
 from repro.analysis.hlo_analysis import analyze_compiled
 from repro.analysis.roofline import roofline_from_report
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape, shape_applicable
+
+TARGET_KIND = "TPU v5 lite"  # the chip the production mesh is made of
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
@@ -73,7 +79,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
         analysis=report,
         roofline=roofline_from_report(
             cfg, report, chips=rec["chips"], mode=meta["mode"],
-            tokens=meta["tokens_per_step"],
+            tokens=meta["tokens_per_step"], device_kind=TARGET_KIND,
         ),
     )
     return rec

@@ -6,7 +6,14 @@ device_count=512`` *before* importing jax; tests and benches see 1 device.
 """
 from __future__ import annotations
 
-from repro.distributed.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding by annotation)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

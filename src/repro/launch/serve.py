@@ -11,6 +11,7 @@ import argparse
 import time
 
 from repro.api import FunctionSpec, Gateway, PoissonWorkload
+from repro.launch.cache import enable_compile_cache
 
 
 def serve(
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--rate", type=float, default=8.0)
     ap.add_argument("--profile", default="resnet50")
     args = ap.parse_args()
+    enable_compile_cache()
     serve(args.arch, args.system, requests=args.requests, rate=args.rate,
           profile=args.profile)
 

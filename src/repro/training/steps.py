@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.compat import shard_map as compat_shard_map
 from repro.training import compression
 from repro.training.loss import lm_loss
 from repro.training.optimizer import OptimizerConfig, adamw_init, adamw_update
@@ -146,11 +145,12 @@ def make_dp_compressed_step(
             metrics.update(om)
             return {"params": new_params, "opt": new_opt, "residuals": new_res}, metrics
 
-        return compat_shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(), P(axes)),  # params replicated; batch row-sharded
             out_specs=(P(), P()),
+            check_vma=False,
         )(state, batch)
 
     return step

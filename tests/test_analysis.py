@@ -67,12 +67,20 @@ def test_roofline_terms_and_dominance():
         "collective_bytes": 1e10, "collective_traffic_bytes": 1e10,
     }
     r = roofline_from_report(cfg, report, chips=256, mode="train",
-                             tokens=1_000_000)
+                             tokens=1_000_000, device_kind="TPU v5 lite")
     assert r["dominant"] == "memory_s"  # 1e12/819e9 > 1e12/197e12
     np.testing.assert_allclose(r["compute_s"], 1e12 / 197e12)
     np.testing.assert_allclose(r["memory_s"], 1e12 / 819e9)
     np.testing.assert_allclose(r["collective_s"], 1e10 / 50e9)
     assert 0 < r["roofline_fraction"] <= 1.5
+
+
+def test_roofline_refuses_an_unknown_device_kind():
+    report = {"flops": 1.0, "dot_flops": 1.0, "hbm_bytes": 1.0,
+              "collective_bytes": 0.0, "collective_traffic_bytes": 0.0}
+    with pytest.raises(KeyError, match="cpu"):
+        roofline_from_report(ARCHS["qwen3-8b"], report, chips=1,
+                             mode="train", tokens=1, device_kind="cpu")
 
 
 def test_model_flops_moe_uses_active_params():

@@ -3,6 +3,7 @@ classification, executor readiness, baselines policy table."""
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from repro.core.baselines import SYSTEMS, get_system
@@ -25,8 +26,8 @@ def _daemon(cap_mb=1024, db=None):
 def _req(fn="f", ro_mb=10, w_mb=2, db=None, uid=None):
     req = Request(function_name=fn)
     if db is not None:
-        db.put(f"{fn}/w", b"W", size=ro_mb * MB)
-        db.put(f"{fn}/in/{req.uuid}", b"X", size=w_mb * MB)
+        db.put(f"{fn}/w", np.zeros(1, np.uint8), size=ro_mb * MB)
+        db.put(f"{fn}/in/{req.uuid}", np.zeros(1, np.uint8), size=w_mb * MB)
     req.in_data = [
         Data(key=f"{fn}/w", size=ro_mb * MB, dtype=DataType.READ_ONLY),
         Data(key=f"{fn}/in/{req.uuid}", size=w_mb * MB, dtype=DataType.WRITABLE),
@@ -97,7 +98,7 @@ class TestDaemon:
         d.release(r1, h1)  # 20MB cached RO
         d.set_evictable_provider(lambda: d.evictable_entries("a"))
         # new function needs 20MB -> must evict a's cached weights
-        db.put("b/w", b"W", size=20 * MB)
+        db.put("b/w", np.zeros(1, np.uint8), size=20 * MB)
         r2 = Request(function_name="b",
                      in_data=[Data(key="b/w", size=20 * MB)])
         h2 = d.prepare(r2)

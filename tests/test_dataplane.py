@@ -4,6 +4,7 @@ daemon/runtime AND the virtual-time simulator twin (docs/dataplane.md)."""
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from repro.core.clock import RealClock
@@ -28,7 +29,7 @@ def _wreq(fn="f", w_mb=8, db=None):
     req = Request(function_name=fn)
     key = f"{fn}/in/{req.uuid}"
     if db is not None:
-        db.put(key, b"X", size=w_mb * MB)
+        db.put(key, np.zeros(1, np.uint8), size=w_mb * MB)
     req.in_data = [Data(key=key, size=w_mb * MB, dtype=DataType.WRITABLE)]
     return req
 
@@ -315,7 +316,7 @@ def test_host_admission_evicts_refcount0_host_entries():
     d, _ = _daemon(db=db, host_capacity=12 * MB)
     # fn a: 8 MB read-only entry, demoted to the HOST tier (refcount 0)
     ra = Request(function_name="a")
-    db.put("a/w", b"W", size=8 * MB)
+    db.put("a/w", np.zeros(1, np.uint8), size=8 * MB)
     ra.in_data = [Data(key="a/w", size=8 * MB, dtype=DataType.READ_ONLY)]
     ha = d.prepare(ra)["a/w"]
     ha.wait(5)
@@ -425,7 +426,7 @@ def test_bytes_loaded_counted_on_completion_only():
     db3 = Database()
     d3, _ = _daemon(db=db3)
     r3 = Request(function_name="f")
-    db3.put("f/w", b"W", size=8 * MB)
+    db3.put("f/w", np.zeros(1, np.uint8), size=8 * MB)
     r3.in_data = [Data(key="f/w", size=8 * MB, dtype=DataType.READ_ONLY)]
     h3 = d3.prepare(r3)["f/w"]
     h3.wait(5)

@@ -8,6 +8,7 @@ import threading
 import time
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # benchmarks/
@@ -41,7 +42,7 @@ def _wreq(fn="f", w_mb=8, db=None, **kw):
     req = Request(function_name=fn, **kw)
     key = f"{fn}/in/{req.uuid}"
     if db is not None:
-        db.put(key, b"X", size=w_mb * MB)
+        db.put(key, np.zeros(1, np.uint8), size=w_mb * MB)
     req.in_data = [Data(key=key, size=w_mb * MB, dtype=DataType.WRITABLE)]
     return req
 
@@ -112,7 +113,7 @@ def test_daemon_residency_walks_tiers_and_never_blocks_on_inflight_loads():
     db = SlowDB(delay=0.4)
     d, _ = _daemon(db=db)
     req = Request(function_name="f")
-    db.put("f/w", b"W", size=8 * MB)
+    db.put("f/w", np.zeros(1, np.uint8), size=8 * MB)
     req.in_data = [Data(key="f/w", size=8 * MB, dtype=DataType.READ_ONLY)]
     assert d.residency("f") == ("none", 0)
     h = d.prepare(req)["f/w"]
@@ -141,7 +142,7 @@ def test_daemon_function_entries_rides_per_function_index():
     d, _ = _daemon(db=db)
     reqs = {}
     for fn in ("a", "b"):
-        db.put(f"{fn}/w", b"W", size=4 * MB)
+        db.put(f"{fn}/w", np.zeros(1, np.uint8), size=4 * MB)
         req = Request(function_name=fn)
         req.in_data = [Data(key=f"{fn}/w", size=4 * MB,
                             dtype=DataType.READ_ONLY)]
@@ -455,7 +456,7 @@ def test_shared_entry_budget_widened_by_late_attacher():
     hold = _wreq(fn="hold", w_mb=8, db=db)
     hh = d.prepare(hold)[hold.in_data[0].key]
     hh.wait(5)
-    db.put("f/w", b"W", size=8 * MB)
+    db.put("f/w", np.zeros(1, np.uint8), size=8 * MB)
 
     def ro_req(budget):
         r = Request(function_name="f", max_retries=budget)

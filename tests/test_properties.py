@@ -4,10 +4,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core.clock import VirtualClock
 from repro.core.datapath import BandwidthBroker

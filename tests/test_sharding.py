@@ -5,17 +5,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS
 from repro.distributed import rules
-from repro.distributed.compat import abstract_mesh
 from repro.models import init_params
 from repro.serving.engine import cache_shapes
 
 MESHES = {
-    "16x16": abstract_mesh((16, 16), ("data", "model")),
-    "2x16x16": abstract_mesh((2, 16, 16), ("pod", "data", "model")),
+    "16x16": AbstractMesh((16, 16), ("data", "model")),
+    "2x16x16": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.training.loss import lm_loss
 from repro.training.optimizer import OptimizerConfig, adamw_init, adamw_update, lr_at
 from repro.training.steps import (

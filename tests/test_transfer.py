@@ -7,6 +7,7 @@ stream is cancelled by release(), and a golden-trace guard that the default
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from repro.api import FunctionSpec, Gateway
@@ -195,7 +196,7 @@ def test_sim_gpu_data_records_actual_contended_span():
 def _wreq(fn, mb, db, deadline_s=None, priority=0):
     req = Request(function_name=fn)
     key = f"{fn}/in/{req.uuid}"
-    db.put(key, b"X", size=mb * MB)
+    db.put(key, np.zeros(1, np.uint8), size=mb * MB)
     req.in_data = [Data(key=key, size=mb * MB, dtype=DataType.WRITABLE)]
     req.deadline_s, req.priority = deadline_s, priority
     return req

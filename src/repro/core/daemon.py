@@ -53,6 +53,7 @@ on a TPU and the A100-40GB figure elsewhere (:func:`capacity_of`).
 """
 from __future__ import annotations
 
+import ctypes
 import enum
 import heapq
 import itertools
@@ -63,6 +64,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.clock import RealClock
 from repro.core.datapath import DataPaths
@@ -168,6 +170,14 @@ class Entry:
     attributed_preemptions: int = 0
     attributed_stalled_s: float = 0.0
 
+    # read-only load timing (InvocationRecord.substages), seconds: when
+    # the chain was last queued for a loader worker, and the queue wait
+    # summed over its (re-)queues. A finished load's numbers wait in
+    # ``load_timing`` until one record claims them (claim_load_timing).
+    enqueued_at: float = 0.0
+    queue_s: float = 0.0
+    load_timing: Optional[Dict[str, float]] = None
+
     def __post_init__(self):
         self.ready = threading.Event()
 
@@ -236,6 +246,15 @@ class Handle:
         return self.entry.size
 
 
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread ``name`` at the OS level as well (Linux;
+    elsewhere a no-op): a profiler trace names its host lines by it."""
+    try:
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)  # PR_SET_NAME
+    except (AttributeError, OSError):
+        pass
+
+
 class LoaderPool:
     """Fixed-size pool of loader workers over a **priority queue**. Bounds
     db/PCIe concurrency to ``size`` and exposes the observed high-water mark
@@ -293,6 +312,7 @@ class LoaderPool:
             job()
 
     def _worker(self) -> None:
+        _name_os_thread(threading.current_thread().name)
         while True:
             with self._cv:
                 while not self._heap and not self._shutdown:
@@ -415,6 +435,20 @@ class MemoryDaemon:
         applies to chunks advanced after the call (an in-flight stream
         simply stops/starts observing yield points)."""
         self.arbiter.set_mode(transfer)
+
+    def claim_load_timing(self, handles: Dict[str, "Handle"]
+                          ) -> Dict[str, float]:
+        """The finished read-only loads of ``handles``'s entries that no
+        record has claimed yet, as summed ``weights_queue``,
+        ``weights_admit`` and ``weights_h2d`` seconds: each load's numbers
+        land on exactly one record, the first sharer to claim them."""
+        out: Dict[str, float] = {}
+        with self._lock:
+            for h in handles.values():
+                timing, h.entry.load_timing = h.entry.load_timing, None
+                for k, v in (timing or {}).items():
+                    out[k] = out.get(k, 0.0) + v
+        return out
 
     def claim_transfer_attribution(self, handles: Dict[str, "Handle"]
                                    ) -> Tuple[int, float]:
@@ -594,12 +628,14 @@ class MemoryDaemon:
     def _entry_key(self, e: Entry) -> AdmissionKey:
         return self._admission_key(e.priority, e.deadline_at)
 
-    def _submit_load(self, job: Callable[[], None],
-                     key: AdmissionKey) -> None:
+    def _submit_load(self, e: Entry) -> None:
+        """Queue (or re-queue) ``e``'s load chain under its current key."""
+        e.enqueued_at = time.monotonic()
         if self.pooled:
-            self._pool.submit(job, key)
+            self._pool.submit(lambda: self._load_full(e), self._entry_key(e))
         else:
-            threading.Thread(target=job, daemon=True).start()
+            threading.Thread(target=self._load_full, args=(e,),
+                             daemon=True).start()
 
     # ------------------------------------------------------------------
     # device memory accounting (contexts + data)
@@ -920,8 +956,7 @@ class MemoryDaemon:
                                 - e.pcie_stream.stalled_s, 0.0)
                         e.pcie_stream = None
                         self.stats["host_promotions"] += 1
-                        self._submit_load(lambda e=e: self._load_full(e),
-                                          self._entry_key(e))
+                        self._submit_load(e)
                     continue
                 e = Entry(
                     function=request.function_name, key=d.key, size=d.size,
@@ -934,8 +969,7 @@ class MemoryDaemon:
                 e.last_used = self.clock.now()
                 self._index_entry(ekey, e)
                 handles[d.key] = Handle(e, self)
-            self._submit_load(lambda e=e: self._load_full(e),
-                              self._entry_key(e))
+            self._submit_load(e)
         return handles
 
     # ------------------------------------------------------------------
@@ -1026,14 +1060,14 @@ class MemoryDaemon:
                 # lock, while the arbiter's is for the single-threaded sim
                 with self._lock:
                     self.stats["preemptions"] += 1
-                self._submit_load(lambda e=e: self._load_full(e),
-                                  self._entry_key(e))
+                self._submit_load(e)
                 return False
 
     def _load_full(self, e: Entry) -> None:
         """Resumable db->host->device chain: dispatches on ``e.load_phase``
         so a preempted leg's continuation (or a host->device promotion,
         which starts at phase "pcie") resumes exactly where it left off."""
+        e.queue_s += time.monotonic() - e.enqueued_at
         if e.load_phase == "db":
             if e.jitter_s > 0.0:
                 # injected loader jitter (docs/resilience.md, "Gray
@@ -1057,9 +1091,10 @@ class MemoryDaemon:
             # chunked stream over the db broker; the payload lookup itself
             # is un-brokered (its timing is the stream)
             try:
-                if not self._drive_stream(e, "db_stream", self.paths.db):
-                    return  # yielded; continuation re-queued
-                payload = self.db.fetch(e.key, None)
+                with TraceAnnotation("sage.load.fetch"):
+                    if not self._drive_stream(e, "db_stream", self.paths.db):
+                        return  # yielded; continuation re-queued
+                    payload = self.db.fetch(e.key, None)
             except _LoadCancelled:
                 self._abort(e)
                 return
@@ -1110,13 +1145,18 @@ class MemoryDaemon:
                 return  # yielded; continuation re-queued
             if e.cancelled:
                 raise _LoadCancelled()
-            self._reserve_device_blocking(
-                e.size, time.monotonic() + self.load_timeout_s, entry=e
-            )
+            t0 = time.monotonic()
+            with TraceAnnotation("sage.wait.admit"):
+                self._reserve_device_blocking(
+                    e.size, t0 + self.load_timeout_s, entry=e
+                )
+            t1 = time.monotonic()
             # host -> this daemon's device; the entry turns DEVICE only
             # once the bytes are in HBM
-            dev = jax.block_until_ready(
-                jax.device_put(e.host_obj, self.device))
+            with TraceAnnotation("sage.load.h2d"):
+                dev = jax.block_until_ready(
+                    jax.device_put(e.host_obj, self.device))
+            t2 = time.monotonic()
         except _LoadCancelled:
             self._abort(e)
             return
@@ -1129,6 +1169,11 @@ class MemoryDaemon:
                 return
             e.dev_obj = dev
             e.tier = Tier.DEVICE
+            if e.read_only:
+                e.load_timing = {"weights_queue": e.queue_s,
+                                 "weights_admit": t1 - t0,
+                                 "weights_h2d": t2 - t1}
+            e.queue_s = 0.0
             # bytes moved are accounted on COMPLETION: a failed or
             # cancelled load rolls through _fail/_abort and never lands
             # here, so stats["loads"]/["bytes_loaded"] no longer overstate

@@ -20,6 +20,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.baselines import SystemPolicy
 from repro.core.daemon import (
     GPU_CONTEXT_BYTES, DataLoadError, Handle, MemoryDaemon, NodeLostError,
@@ -177,13 +179,15 @@ class FunctionEngine:
     # ------------------------------------------------------------------
     def _attribute_transfer(self, record: InvocationRecord,
                             handles: Dict[str, Handle]) -> None:
-        """Claim the handles' not-yet-attributed preemption/stall totals
-        for this record. Claim-once semantics live in the daemon: a pause
-        on a shared entry lands on exactly ONE sharer's record, so
-        Telemetry totals stay comparable across backends."""
+        """Claim the handles' not-yet-attributed preemption/stall totals,
+        and the timing of their finished weight loads, for this record.
+        Claim-once semantics live in the daemon: a pause on a shared entry,
+        or a load, lands on exactly ONE sharer's record, so Telemetry
+        totals stay comparable across backends."""
         p, s = self.daemon.claim_transfer_attribution(handles)
         record.preemptions += p
         record.stalled_s += s
+        record.substages.update(self.daemon.claim_load_timing(handles))
 
     def idle_memory_bytes(self) -> int:
         """Memory pinned by warm-but-idle state (Fig 12 accounting)."""
@@ -249,7 +253,7 @@ class FunctionEngine:
                             if request is not None else (0, None))
         budget = request.max_retries if request is not None else None
         t0 = time.monotonic()
-        with self._ctx_build_lock:
+        with TraceAnnotation("sage.ctx"), self._ctx_build_lock:
             if inst.gpu_ctx is None:
                 self.daemon.reserve_context(self.fn.context_bytes,
                                             priority=prio,
@@ -318,10 +322,10 @@ class FunctionEngine:
         # the finally block still releases the handles — which cancels any
         # still-loading writable entries — and frees the instance, so a
         # failed invocation neither leaks accounting nor wedges the engine.
-        t_par0 = time.monotonic()
-        handles = self.daemon.prepare(
-            request, system_shares_ro=self.policy.share_read_only
-        )
+        with TraceAnnotation("sage.prepare"):
+            handles = self.daemon.prepare(
+                request, system_shares_ro=self.policy.share_read_only
+            )
         try:
             self._hedge_check(request)  # before the expensive compile...
             ctx_s = self._ensure_ctx(inst, request)
@@ -331,14 +335,14 @@ class FunctionEngine:
             result, data_wait = self._run_handler(inst, request, handles, record)
             record.stages["gpu_data"] = data_wait
             record.stages["cpu_data"] = 0.0  # folded into daemon pipeline (async)
-            record.setup_wall = time.monotonic() - t_par0 - record.stages.get("compute", 0.0)
             return result
         finally:
-            self._attribute_transfer(record, handles)
-            self.daemon.release(request, handles)
-            with self._lock:
-                inst.busy = False
-                inst.ladder.on_complete(self.clock.now())
+            with TraceAnnotation("sage.release"):
+                self._attribute_transfer(record, handles)
+                self.daemon.release(request, handles)
+                with self._lock:
+                    inst.busy = False
+                    inst.ladder.on_complete(self.clock.now())
 
     # ------------------------------------------------------------------
     # FixedGSL / FixedGSL-F: serial setup, per-invocation instances
@@ -464,16 +468,20 @@ class FunctionEngine:
     # ------------------------------------------------------------------
     def _run_handler(self, inst: Instance, request: Request, handles, record=None):
         """Run the user handler through the taxon shim; returns
-        (result, data_wait_seconds). ``record`` gets compute/return stages."""
+        (result, data_wait_seconds), the wait being this invocation's own.
+        ``record`` gets compute/return stages, and the compute stage split
+        into the compute-lock queue and the forward."""
         shim = TaxonShim(self.daemon, self.executor, request, handles)
         shim.gpu_ctx = inst.gpu_ctx
-        w0 = self.executor.wait_time
         t0 = time.monotonic()
         result = self.fn.handler(shim, request)
         wall = time.monotonic() - t0
-        data_wait = self.executor.wait_time - w0
+        data_wait, queue = shim.data_wait_s, shim.compute_queue_s
         if record is not None:
-            record.stages["compute"] = max(wall - data_wait, 0.0)
+            forward = max(wall - queue - data_wait, 0.0)
+            record.substages["compute_queue"] = queue
+            record.substages["forward"] = forward
+            record.stages["compute"] = queue + forward
             record.stages["return_result"] = 0.0001
             # batch attribution stamped on the request by the compute
             # plane's collector (docs/compute.md); defaults when off

@@ -10,33 +10,32 @@ additionally bounds waits on *live* loads (None = unbounded, the daemon's
 own load deadline is the backstop)."""
 from __future__ import annotations
 
-import threading
+import time
 from typing import Any, Dict, Optional, Tuple
 
-from repro.core.daemon import DataLoadError, Handle
+from jax.profiler import TraceAnnotation
+
+from repro.core.daemon import Handle
 
 
 class KernelExecutor:
     def __init__(self, clock=None, wait_timeout: Optional[float] = None):
         self.clock = clock
         self.wait_timeout = wait_timeout
-        self._lock = threading.Lock()
-        self.launched = 0
-        self.wait_time = 0.0  # time spent blocked on data readiness
 
     def _resolve(self, x):
         if isinstance(x, Handle):
             return x.wait(self.wait_timeout)
         return x
 
-    def launch(self, fn, args: Tuple, kwargs: Dict) -> Any:
-        import time as _t
-
-        t0 = _t.monotonic()
-        rargs = [self._resolve(a) for a in args]
-        rkwargs = {k: self._resolve(v) for k, v in kwargs.items()}
-        waited = _t.monotonic() - t0
-        with self._lock:
-            self.wait_time += waited
-            self.launched += 1
-        return fn(*rargs, **rkwargs)
+    def launch(self, fn, args: Tuple, kwargs: Dict) -> Tuple[Any, float]:
+        """Resolve the operand handles, then call ``fn``; returns (result,
+        seconds spent waiting for operand data) — the caller's own wait,
+        which the shim charges to its invocation."""
+        t0 = time.monotonic()
+        with TraceAnnotation("sage.wait.data"):
+            rargs = [self._resolve(a) for a in args]
+            rkwargs = {k: self._resolve(v) for k, v in kwargs.items()}
+        waited = time.monotonic() - t0
+        with TraceAnnotation("sage.launch"):
+            return fn(*rargs, **rkwargs), waited

@@ -15,10 +15,12 @@ virtual-time twin for trace-scale experiments is ``core.simulator``.
 from __future__ import annotations
 
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.baselines import SystemPolicy, get_system
 from repro.core.clock import RealClock
@@ -149,7 +151,8 @@ class SageRuntime:
         under a fractional slice grant instead, optionally batched with
         concurrent same-function arrivals. The wrapper reads
         ``self._plane`` per call, so ``set_compute`` applies to functions
-        registered before it; it wraps only the handler's compute."""
+        registered before it; it wraps only the handler's compute. The
+        wait for the node lock is stored on the shim (``compute_queue_s``)."""
         inner = fn.handler
         runtime = self
 
@@ -158,10 +161,17 @@ class SageRuntime:
             if plane is not None:
                 return plane.run(wrapped, inner, shim, request)
             lock = runtime._compute_lock
-            if lock is not None:
-                with lock:
+            if lock is None:
+                return inner(shim, request)
+            t0 = time.monotonic()
+            with TraceAnnotation("sage.wait.compute_lock"):
+                lock.acquire()
+            shim.compute_queue_s = time.monotonic() - t0
+            try:
+                with TraceAnnotation("sage.forward"):
                     return inner(shim, request)
-            return inner(shim, request)
+            finally:
+                lock.release()
 
         import dataclasses
 
@@ -204,6 +214,7 @@ class SageRuntime:
                 # account the stretch where it was served — the per-node
                 # latency profiler reads stage timings, not durations
                 rec.stages["compute"] = rec.stages.get("compute", 0.0) + extra
+                rec.substages["forward"] += extra
             rec.result = result
             return result
         except Exception as exc:

@@ -21,14 +21,16 @@ class TaxonShim:
         self.executor = executor
         self.request = request
         self._handles = handles  # pre-loaded by the engine's prepare()
-        self.memory_calls = 0
-        self.kernel_calls = 0
+        # this invocation's own waits, in seconds: for operand data inside
+        # its kernel launches, and for the node's compute lock (set by the
+        # runtime's handler wrapper)
+        self.data_wait_s = 0.0
+        self.compute_queue_s = 0.0
 
     # ---- memory calls (-> daemon) -------------------------------------
     def sage_load_to_gpu(self, key: str) -> Handle:
         """Async: returns immediately with a handle; the daemon may still be
         loading (§5.2.1: 'SageLoadToGPU is an asynchronous operation')."""
-        self.memory_calls += 1
         h = self._handles.get(key)
         if h is None:
             # datum not declared in the request: load on demand (no overlap
@@ -47,18 +49,17 @@ class TaxonShim:
         return h
 
     def cuda_malloc(self, key: str, nbytes: int) -> Handle:
-        self.memory_calls += 1
         h = self.daemon.alloc(self.request, key, nbytes)
         self._handles[key] = h
         return h
 
     def sage_dump_to_db(self, key: str, value: Any, size: int = 0) -> None:
-        self.memory_calls += 1
         self.daemon.db.put(key, value, size=size)
 
     # ---- kernel calls (-> executor) ------------------------------------
     def launch_kernel(self, fn, *args, **kwargs):
         """Forwarded to the kernel executor, which verifies with the daemon
         that every operand handle is ready before launching (§5.2.2)."""
-        self.kernel_calls += 1
-        return self.executor.launch(fn, args, kwargs)
+        result, waited = self.executor.launch(fn, args, kwargs)
+        self.data_wait_s += waited
+        return result

@@ -61,6 +61,14 @@ class InvocationRecord:
     end_t: float = 0.0
     warm_stage: Optional[int] = None  # exit-policy stage reused (None = cold)
     stages: Dict[str, float] = field(default_factory=dict)  # stage -> seconds
+    # finer durations inside the stages, seconds; filled by the threaded
+    # runtime only (the simulator leaves it empty). "compute_queue" (wait
+    # for the node's compute lock) + "forward" (lock held, less this
+    # invocation's data wait) == stages["compute"]; "weights_queue",
+    # "weights_admit", "weights_h2d": one read-only weight load's loader
+    # queue wait, device admission wait and host -> HBM copy, on the one
+    # record that claimed that load
+    substages: Dict[str, float] = field(default_factory=dict)
     dropped: bool = False
     error: Optional[str] = None  # "Type: message" when the invocation failed
     deadline_s: Optional[float] = None  # per-request SLO (recorded, not enforced)
@@ -77,7 +85,6 @@ class InvocationRecord:
     # the delta over the invocation's in-flight span in the runtime).
     preemptions: int = 0
     stalled_s: float = 0.0
-    setup_wall: float = 0.0  # wall time of the (possibly parallel) setup span
     result: Any = None       # handler return value (real runtime only)
     # resilience attribution (docs/resilience.md): failure taxonomy class
     # (one of ERROR_CLASSES when error is set) and how many times the

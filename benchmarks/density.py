@@ -118,7 +118,9 @@ def run_runtime(compute, quick: bool = False,
         for name in fn_names:
 
             def handler(shim, request, _c=compute_s):
-                time.sleep(_c)
+                # the modeled kernel is a synchronous launch: under the
+                # exclusive lock it holds the node for its whole span
+                shim.launch_kernel(time.sleep, _c)
 
             rt.register_function(GPUFunction(
                 name=name, handler=handler,

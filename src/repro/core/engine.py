@@ -469,8 +469,9 @@ class FunctionEngine:
     def _run_handler(self, inst: Instance, request: Request, handles, record=None):
         """Run the user handler through the taxon shim; returns
         (result, data_wait_seconds), the wait being this invocation's own.
-        ``record`` gets compute/return stages, and the compute stage split
-        into the compute-lock queue and the forward."""
+        ``record`` gets compute/return stages, the compute stage split
+        into the compute-lock queue and the forward, and whether a launch
+        was enqueued behind an unfinished program of the node."""
         shim = TaxonShim(self.daemon, self.executor, request, handles)
         shim.gpu_ctx = inst.gpu_ctx
         t0 = time.monotonic()
@@ -483,6 +484,7 @@ class FunctionEngine:
             record.substages["forward"] = forward
             record.stages["compute"] = queue + forward
             record.stages["return_result"] = 0.0001
+            record.launch_overlapped = shim.overlapped
             # batch attribution stamped on the request by the compute
             # plane's collector (docs/compute.md); defaults when off
             record.batch_size = getattr(request, "batch_size", 1)

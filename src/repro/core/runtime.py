@@ -15,7 +15,6 @@ virtual-time twin for trace-scale experiments is ``core.simulator``.
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
@@ -88,13 +87,13 @@ class SageRuntime:
             pooled=self.policy.name.startswith("sage"),
         )
         self.device = self.daemon.device  # contexts compile for this device
-        self.executor = KernelExecutor(self.clock)
+        # the executor owns the node's compute lock (None: no lock)
+        self.executor = KernelExecutor(self.clock, serialize=serialize_compute)
         self.telemetry = Telemetry()
         self.engines: Dict[str, FunctionEngine] = {}
         self.time_scale = time_scale
         self.exit_ttl = exit_ttl
         self._pool = ThreadPoolExecutor(max_workers=max_workers)
-        self._compute_lock = threading.Lock() if serialize_compute else None
         # shared compute plane (docs/compute.md): when on, the whole-node
         # handler lock is replaced by the fractional slice budget (+
         # optional same-function batching). The handler wrapper consults
@@ -145,14 +144,18 @@ class SageRuntime:
         )
 
     def _wrap_compute(self, fn: GPUFunction) -> GPUFunction:
-        """One GPU: by default kernel executions serialize under the
-        whole-node lock (matches Throughput_theo = 1/T_comp). With a
-        shared compute plane attached (docs/compute.md) the handler runs
-        under a fractional slice grant instead, optionally batched with
-        concurrent same-function arrivals. The wrapper reads
-        ``self._plane`` per call, so ``set_compute`` applies to functions
-        registered before it; it wraps only the handler's compute. The
-        wait for the node lock is stored on the shim (``compute_queue_s``)."""
+        """One GPU: by default the node's kernel programs run one at a time
+        (matches Throughput_theo = 1/T_comp), through the compute lock the
+        executor owns. The lock covers only a launch's enqueue: the launch
+        resolves its operands before it, and the handler fetches its result
+        after it, while the device runs the next caller's program
+        (``executor.LaunchGate``). With a shared compute plane attached
+        (docs/compute.md) the handler runs under a fractional slice grant
+        instead, optionally batched with concurrent same-function arrivals,
+        and its launches take no lock. The wrapper reads ``self._plane``
+        per call, so ``set_compute`` applies to functions registered before
+        it. The waits for the lock are summed on the shim
+        (``compute_queue_s``)."""
         inner = fn.handler
         runtime = self
 
@@ -160,18 +163,9 @@ class SageRuntime:
             plane = runtime._plane
             if plane is not None:
                 return plane.run(wrapped, inner, shim, request)
-            lock = runtime._compute_lock
-            if lock is None:
+            shim.serialize = True
+            with TraceAnnotation("sage.forward"):
                 return inner(shim, request)
-            t0 = time.monotonic()
-            with TraceAnnotation("sage.wait.compute_lock"):
-                lock.acquire()
-            shim.compute_queue_s = time.monotonic() - t0
-            try:
-                with TraceAnnotation("sage.forward"):
-                    return inner(shim, request)
-            finally:
-                lock.release()
 
         import dataclasses
 

@@ -21,11 +21,17 @@ class TaxonShim:
         self.executor = executor
         self.request = request
         self._handles = handles  # pre-loaded by the engine's prepare()
-        # this invocation's own waits, in seconds: for operand data inside
-        # its kernel launches, and for the node's compute lock (set by the
-        # runtime's handler wrapper)
+        # set by the runtime's handler wrapper where the node's launches
+        # go through its compute lock (not under a shared compute plane)
+        self.serialize = False
+        # this invocation's own waits, in seconds, summed over its kernel
+        # launches: for operand data, and for the node's compute lock and
+        # room on the device; and whether a launch was enqueued behind an
+        # unfinished program of the node (None: no launch went through
+        # the lock)
         self.data_wait_s = 0.0
         self.compute_queue_s = 0.0
+        self.overlapped: Optional[bool] = None
 
     # ---- memory calls (-> daemon) -------------------------------------
     def sage_load_to_gpu(self, key: str) -> Handle:
@@ -60,6 +66,10 @@ class TaxonShim:
     def launch_kernel(self, fn, *args, **kwargs):
         """Forwarded to the kernel executor, which verifies with the daemon
         that every operand handle is ready before launching (§5.2.2)."""
-        result, waited = self.executor.launch(fn, args, kwargs)
-        self.data_wait_s += waited
+        result, waits = self.executor.launch(fn, args, kwargs,
+                                             serialize=self.serialize)
+        self.data_wait_s += waits.data_s
+        self.compute_queue_s += waits.queue_s
+        if waits.overlapped is not None:
+            self.overlapped = bool(self.overlapped) or waits.overlapped
         return result

@@ -62,13 +62,20 @@ class InvocationRecord:
     warm_stage: Optional[int] = None  # exit-policy stage reused (None = cold)
     stages: Dict[str, float] = field(default_factory=dict)  # stage -> seconds
     # finer durations inside the stages, seconds; filled by the threaded
-    # runtime only (the simulator leaves it empty). "compute_queue" (wait
-    # for the node's compute lock) + "forward" (lock held, less this
-    # invocation's data wait) == stages["compute"]; "weights_queue",
+    # runtime only (the simulator leaves it empty). "compute_queue" (wait,
+    # once the operands are resolved, for the node's compute lock and for
+    # room behind the program running on the device) + "forward" (the
+    # rest of the handler: its enqueue, the wait for its own result, the
+    # copy out; less its data wait) == stages["compute"]; "weights_queue",
     # "weights_admit", "weights_h2d": one read-only weight load's loader
     # queue wait, device admission wait and host -> HBM copy, on the one
     # record that claimed that load
     substages: Dict[str, float] = field(default_factory=dict)
+    # runtime only: whether a launch of this invocation was enqueued while
+    # an earlier program of the node was still unfinished on the device
+    # (None: no launch went through the node's compute lock; the
+    # simulator leaves it unset)
+    launch_overlapped: Optional[bool] = None
     dropped: bool = False
     error: Optional[str] = None  # "Type: message" when the invocation failed
     deadline_s: Optional[float] = None  # per-request SLO (recorded, not enforced)

@@ -108,9 +108,10 @@ def test_compute_splits_and_each_weight_load_lands_on_one_record():
 
 
 def test_queued_warm_invocation_is_not_charged_the_cold_ones_data_wait():
-    """A warm invocation queued for the compute lock behind a cold one that
-    waits, inside the lock, for a slowed weight load: the warm one's data
-    wait is its own (about 0), and its queue is the cold one's lock hold."""
+    """A cold invocation waits for a slowed weight load before it takes the
+    node's compute lock: meanwhile a warm invocation of another function
+    on the node runs and completes, with no queue for the lock and a data
+    wait of its own (about 0)."""
     rt, (warm,) = _runtime_with(1, time_scale=1.0)
     try:
         # the slow function: 800 MiB declared, so its modeled db and PCIe
@@ -122,23 +123,21 @@ def test_queued_warm_invocation_is_not_charged_the_cold_ones_data_wait():
             rt.sage_run(make_request(rt.db, fn, seed=1))
         rt.daemon.demote_to_host("slow")
         rt.daemon.drop_host("slow")  # the next call loads from the db
-        lock = rt._compute_lock
         f_cold = rt.submit(make_request(rt.db, slow, seed=2))
         t0 = time.monotonic()
-        while not lock.locked():
-            assert time.monotonic() - t0 < 30, "the cold call never ran"
+        while rt.daemon.residency("slow")[0] != "loading":
+            assert time.monotonic() - t0 < 30, "the cold load never started"
             time.sleep(0.001)
-        f_warm = rt.submit(make_request(rt.db, warm, seed=3))
+        rt.submit(make_request(rt.db, warm, seed=3)).result(timeout=60)
+        assert not f_cold.done()  # the warm call ran beside the cold load
         f_cold.result(timeout=60)
-        f_warm.result(timeout=60)
         by_fn = {r.function: r for r in rt.telemetry.snapshot()[-2:]}
     finally:
         rt.shutdown()
     c, w = by_fn["slow"], by_fn["f0"]
-    cold_hold = c.stages["gpu_data"] + c.substages["forward"]
-    assert c.stages["gpu_data"] > 0.3  # the cold one waited inside the lock
+    assert c.stages["gpu_data"] > 0.3  # the cold one waited for its load
     assert w.stages["gpu_data"] < 0.05
-    # it queued from just after the cold one took the lock to its release
-    assert cold_hold - 0.2 <= w.substages["compute_queue"] <= cold_hold + 0.05
-    assert w.stages["compute"] == pytest.approx(
-        w.substages["compute_queue"] + w.substages["forward"])
+    assert w.substages["compute_queue"] < 0.05
+    for r in (c, w):
+        assert r.stages["compute"] == pytest.approx(
+            r.substages["compute_queue"] + r.substages["forward"])

@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 from repro.configs.base import SHAPES, ModelConfig, ShapeConfig, shape_applicable
+from repro.configs.granite_4_0_h_small import CONFIG as GRANITE_4_0_H_SMALL
+from repro.configs.granite_4_0_h_small import RANK0 as GRANITE_4_0_H_SMALL_RANK0
 from repro.configs.jamba_1_5_large import CONFIG as JAMBA_1_5_LARGE
 from repro.configs.llama4_maverick_400b import CONFIG as LLAMA4_MAVERICK
 from repro.configs.mamba2_780m import CONFIG as MAMBA2_780M
@@ -26,6 +28,8 @@ ARCHS = {
         QWEN3_8B,
         PHI4_MINI,
         WHISPER_SMALL,
+        GRANITE_4_0_H_SMALL,
+        GRANITE_4_0_H_SMALL_RANK0,
     ]
 }
 

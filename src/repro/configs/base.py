@@ -44,6 +44,8 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 1_000_000.0
+    rope: bool = True  # False: NoPE, no position embedding at all
+    attn_scale: float = 0.0  # 0 -> 1/sqrt(head_dim)
     mrope_sections: Tuple[int, ...] = ()  # vlm M-RoPE (t, h, w) half-dim split
     causal: bool = True
     # MoE
@@ -52,7 +54,14 @@ class ModelConfig:
     moe_every: int = 1  # MoE ffn on layers where (i % moe_every == moe_every-1)
     moe_shared_expert: bool = False
     expert_d_ff: int = 0  # 0 -> d_ff
+    shared_expert_d_ff: int = 0  # 0 -> the routed experts' width
     moe_capacity_factor: float = 1.25
+    # dropless routing (every routed (token, expert) pair is computed) over
+    # num_experts, of which this device holds experts_held (0 -> all),
+    # numbered from expert_offset: one rank of an expert-parallel layer
+    moe_dropless: bool = False
+    experts_held: int = 0
+    expert_offset: int = 0
     router_aux_loss: float = 0.01
     # SSM (mamba2)
     ssm_state: int = 0
@@ -60,7 +69,8 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_chunk: int = 128
-    attn_every: int = 0  # hybrid: attn mixer on layers where (i % attn_every == 0)
+    attn_every: int = 0  # hybrid: attn mixer on layers where (i % attn_every == attn_offset)
+    attn_offset: int = 0
     # encoder-decoder (whisper)
     is_encoder_decoder: bool = False
     encoder_layers: int = 0
@@ -68,6 +78,12 @@ class ModelConfig:
     # norm / embeddings
     rmsnorm_eps: float = 1e-6
     tie_embeddings: bool = False
+    # muP multipliers: embeddings x embedding_multiplier, each sublayer's
+    # output x residual_multiplier before it joins the residual stream,
+    # logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # numerics
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
@@ -86,6 +102,14 @@ class ModelConfig:
             f"{self.name}: num_heads {self.num_heads} not divisible by "
             f"kv heads {self.num_kv_heads}"
         )
+        if self.num_experts:
+            assert self.expert_offset + self.moe_held <= self.num_experts, (
+                f"{self.name}: held experts {self.expert_offset}.."
+                f"{self.expert_offset + self.moe_held - 1} outside "
+                f"{self.num_experts}")
+            assert self.moe_dropless or self.moe_held == self.num_experts, (
+                f"{self.name}: only the dropless layer holds a share of "
+                f"the experts")
 
     # ------------------------------------------------------------------
     # Period structure
@@ -114,7 +138,8 @@ class ModelConfig:
             if self.family == "ssm":
                 mixer = "mamba"
             elif self.attn_every > 1:
-                mixer = "attn" if i % self.attn_every == 0 else "mamba"
+                mixer = ("attn" if i % self.attn_every == self.attn_offset
+                         else "mamba")
             else:
                 mixer = "attn"
             if self.family == "ssm":
@@ -154,6 +179,15 @@ class ModelConfig:
     def moe_d_ff(self) -> int:
         return self.expert_d_ff or self.d_ff
 
+    @property
+    def moe_shared_d_ff(self) -> int:
+        return self.shared_expert_d_ff or self.moe_d_ff
+
+    @property
+    def moe_held(self) -> int:
+        """Experts whose weights this device holds and computes."""
+        return self.experts_held or self.num_experts
+
     # ------------------------------------------------------------------
     # Parameter counting (analytic; used for roofline MODEL_FLOPS and the
     # serverless memory daemon's read-only size accounting)
@@ -170,9 +204,9 @@ class ModelConfig:
         dense_ffn = 3 * d * self.d_ff  # SwiGLU: gate, up, down
         moe_ffn = 0
         if self.num_experts:
-            moe_ffn = self.num_experts * 3 * d * self.moe_d_ff + d * self.num_experts
+            moe_ffn = self.moe_held * 3 * d * self.moe_d_ff + d * self.num_experts
             if self.moe_shared_expert:
-                moe_ffn += 3 * d * self.moe_d_ff
+                moe_ffn += 3 * d * self.moe_shared_d_ff
         di, ns, nh = self.ssm_d_inner, self.ssm_state, self.ssm_nheads
         mamba = (
             d * (2 * di + 2 * ns + nh)  # in_proj -> z,x,B,C,dt
@@ -220,7 +254,7 @@ class ModelConfig:
         active_moe = n_moe_ffn * (
             self.experts_per_token * 3 * self.d_model * self.moe_d_ff
             + self.d_model * self.num_experts
-            + (3 * self.d_model * self.moe_d_ff if self.moe_shared_expert else 0)
+            + (3 * self.d_model * self.moe_shared_d_ff if self.moe_shared_expert else 0)
         )
         return total + active_moe
 
@@ -241,6 +275,13 @@ class ModelConfig:
         )
         if self.num_experts:
             small.update(num_experts=4, experts_per_token=min(self.experts_per_token, 2), expert_d_ff=64)
+        if self.moe_dropless:
+            # 8 routed experts, of which the same fraction held as published
+            held = max(1, self.moe_held * 8 // self.num_experts)
+            small.update(num_experts=8, experts_per_token=min(self.experts_per_token, 4),
+                         experts_held=held, expert_offset=0)
+        if self.shared_expert_d_ff:
+            small.update(shared_expert_d_ff=96)
         if self.ssm_state:
             small.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16)
         if self.mrope_sections:

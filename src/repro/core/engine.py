@@ -470,8 +470,9 @@ class FunctionEngine:
         """Run the user handler through the taxon shim; returns
         (result, data_wait_seconds), the wait being this invocation's own.
         ``record`` gets compute/return stages, the compute stage split
-        into the compute-lock queue and the forward, and whether a launch
-        was enqueued behind an unfinished program of the node."""
+        into the compute-lock queue and the forward, whether a launch
+        was enqueued behind an unfinished program of the node, and the
+        expert counters the handler read."""
         shim = TaxonShim(self.daemon, self.executor, request, handles)
         shim.gpu_ctx = inst.gpu_ctx
         t0 = time.monotonic()
@@ -485,6 +486,8 @@ class FunctionEngine:
             record.stages["compute"] = queue + forward
             record.stages["return_result"] = 0.0001
             record.launch_overlapped = shim.overlapped
+            record.expert_rows = shim.expert_rows
+            record.expert_rows_max = shim.expert_rows_max
             # batch attribution stamped on the request by the compute
             # plane's collector (docs/compute.md); defaults when off
             record.batch_size = getattr(request, "batch_size", 1)

@@ -41,8 +41,17 @@ def host_params(cfg: ModelConfig, seed: int = 0) -> Any:
 
 
 def served_logits(cfg: ModelConfig):
-    """What one invocation computes: the logits of a token batch."""
-    return lambda p, t: forward(cfg, p, {"tokens": t})[0]
+    """What one invocation computes: the logits of a token batch; for a
+    model with dropless experts, (logits, its expert counters as int32
+    (2,)), the counters of ``forward``."""
+    if not cfg.moe_dropless:
+        return lambda p, t: forward(cfg, p, {"tokens": t})[0]
+
+    def logits_and_counters(p, t):
+        logits, _, counters = forward(cfg, p, {"tokens": t}, counters=True)
+        return logits, counters
+
+    return logits_and_counters
 
 
 def make_model_function(
@@ -87,9 +96,13 @@ def make_model_function(
     def handler(shim, request: Request):
         w = shim.sage_load_to_gpu(weights_key)
         x = shim.sage_load_to_gpu(request.in_data[1].key)
-        logits = shim.launch_kernel(shim.gpu_ctx, w, x)
+        out = shim.launch_kernel(shim.gpu_ctx, w, x)
+        logits, counters = out if cfg.moe_dropless else (out, None)
         out_key = f"{fn_name}/out/{request.uuid}"
         shim.sage_dump_to_db(out_key, np.asarray(logits[:, -1, :8]))
+        if counters is not None:  # fetched after the launch, outside the lock
+            shim.expert_rows, shim.expert_rows_max = (
+                int(c) for c in np.asarray(counters))
         return out_key
 
     return GPUFunction(

@@ -32,6 +32,10 @@ class TaxonShim:
         self.data_wait_s = 0.0
         self.compute_queue_s = 0.0
         self.overlapped: Optional[bool] = None
+        # the expert counters the handler read from its program's outputs
+        # (None: the model has no dropless experts)
+        self.expert_rows: Optional[int] = None
+        self.expert_rows_max: Optional[int] = None
 
     # ---- memory calls (-> daemon) -------------------------------------
     def sage_load_to_gpu(self, key: str) -> Handle:

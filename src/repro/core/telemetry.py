@@ -76,6 +76,12 @@ class InvocationRecord:
     # (None: no launch went through the node's compute lock; the
     # simulator leaves it unset)
     launch_overlapped: Optional[bool] = None
+    # runtime only, for a model with dropless experts (None otherwise, and
+    # in the simulator): the (token, expert) rows the experts held on the
+    # node computed, summed over layers, and the rows of the busiest held
+    # expert, the largest over layers
+    expert_rows: Optional[int] = None
+    expert_rows_max: Optional[int] = None
     dropped: bool = False
     error: Optional[str] = None  # "Type: message" when the invocation failed
     deadline_s: Optional[float] = None  # per-request SLO (recorded, not enforced)

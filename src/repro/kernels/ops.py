@@ -14,9 +14,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
+from repro.kernels import grouped_matmul as _gmm
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import ref as _ref
 
@@ -72,3 +74,15 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk=128, initial_state=None,
         return _ssd.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=interp)
     return _ref.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk,
                          initial_state=initial_state)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, out_dtype, tile_rows,
+                   use_pallas="auto"):
+    """Rows ``[o_g, o_g + group_sizes[g])`` of ``lhs`` (M, K) times
+    ``rhs[g]`` (K, N), for each group ``g`` in order from ``o_0 = 0``. Rows
+    past the last group are not computed, and their values are undefined."""
+    use, interp = _resolve(use_pallas)
+    if use:
+        return _gmm.grouped_matmul(lhs, rhs, group_sizes, out_dtype=out_dtype,
+                                   tile_rows=tile_rows, interpret=interp)
+    return lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=out_dtype)

@@ -368,10 +368,10 @@ def attention_forward(
 ):
     """Full-sequence attention (train / prefill). positions: (B, S) or
     (3, B, S) for M-RoPE."""
-    B, S, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x, x)
-    q, k = _rope_qk(cfg, q, k, positions)
-    out = flash_attention_ref(q, k, v, causal=causal)
+    if cfg.rope:
+        q, k = _rope_qk(cfg, q, k, positions)
+    out = flash_attention_ref(q, k, v, causal=causal, scale=cfg.attn_scale or None)
     y = _out_proj(cfg, p, out)
     if return_kv:
         return y, (k, v)
@@ -413,13 +413,15 @@ def attention_decode(
         pos_rope = positions[:, None]  # (B, 1)
         scatter_pos = positions
     q, k, v = _project_qkv(cfg, p, x, x)
-    q, k = _rope_qk(cfg, q, k, pos_rope)
+    if cfg.rope:
+        q, k = _rope_qk(cfg, q, k, pos_rope)
     # scatter the new token's kv at per-sequence positions
     bidx = jnp.arange(B)
     k_cache = k_cache.at[bidx, scatter_pos].set(k[:, 0].astype(k_cache.dtype))
     v_cache = v_cache.at[bidx, scatter_pos].set(v[:, 0].astype(v_cache.dtype))
     lengths = scatter_pos + 1
-    out = decode_attention_ref(q, k_cache, v_cache, lengths)
+    out = decode_attention_ref(q, k_cache, v_cache, lengths,
+                               scale=cfg.attn_scale or None)
     return _out_proj(cfg, p, out), k_cache, v_cache
 
 
@@ -455,18 +457,20 @@ def mlp_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 
 
 def init_moe(cfg: ModelConfig, key) -> dict:
+    """The router over all ``num_experts``; the weights of the experts this
+    device holds (``moe_held``); the shared expert, if any."""
     dt = pdtype(cfg)
-    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.moe_held
     ks = jax.random.split(key, 5)
     scale = 1.0 / math.sqrt(d)
     p = {
-        "router": dense_init(ks[0], d, e, jnp.float32),  # router kept fp32
+        "router": dense_init(ks[0], d, cfg.num_experts, jnp.float32),  # router kept fp32
         "wg": _uniform(ks[1], (e, d, f), scale, dt),
         "wu": _uniform(ks[2], (e, d, f), scale, dt),
         "wd": _uniform(ks[3], (e, f, d), 1.0 / math.sqrt(f), dt),
     }
     if cfg.moe_shared_expert:
-        p["shared"] = init_mlp(cfg, ks[4], cfg.moe_d_ff)
+        p["shared"] = init_mlp(cfg, ks[4], cfg.moe_shared_d_ff)
     return p
 
 
@@ -533,6 +537,74 @@ def moe_forward(
     return y.astype(x.dtype), aux.astype(jnp.float32)
 
 
+#: rows of one tile of the held experts' grouped products: each expert's
+#: rows start a tile, so a kernel that visits only the tiles of its groups
+#: reads each expert's weights once per tile of its rows
+EXPERT_ROW_TILE = 128
+
+
+def moe_dropless_forward(
+    cfg: ModelConfig, p: dict, x: jax.Array  # (B, S, D)
+) -> Tuple[jax.Array, jax.Array]:
+    """Dropless token-choice top-k over ``num_experts`` experts, of which this
+    device holds ``moe_held``, numbered from ``expert_offset``: one rank of
+    an expert-parallel layer, run without its exchange.
+
+    The router (float32 at full precision, no bias) scores every expert;
+    each token takes its ``experts_per_token`` highest logits and weighs
+    them by a softmax over those alone. The (token, expert) pairs of held
+    experts are sorted by expert and run as grouped products, each held
+    expert over exactly its own rows; the pairs of experts held elsewhere
+    contribute nothing here. The shared expert, if any, runs on every token.
+    The products run over a buffer of rows sized for the most the held
+    experts can take (every token picking ``min(K, moe_held)`` of them), so
+    nothing is dropped; only the groups' own tiles are computed.
+
+    Returns (y, counters): counters is int32 (2,), the rows the held experts
+    computed and the rows of the busiest held expert.
+    """
+    from repro.kernels import ops  # imports this module: not at the top
+
+    with jax.named_scope("sage.moe"):  # names its ops in a device trace
+        B, S, D = x.shape
+        K, H, tile = cfg.experts_per_token, cfg.moe_held, EXPERT_ROW_TILE
+        T = B * S
+        ct = cdtype(cfg)
+        xf = x.reshape(T, D)
+        logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                         precision=lax.Precision.HIGHEST)  # (T, E)
+        top_logits, top_idx = lax.top_k(logits, K)  # (T, K)
+        gates = jax.nn.softmax(top_logits, axis=-1)
+        local = (top_idx - cfg.expert_offset).reshape(T * K)
+        held = (local >= 0) & (local < H)
+        # each held (token, expert) pair's row: its expert's rows start a tile
+        onehot = jax.nn.one_hot(jnp.where(held, local, H), H, dtype=jnp.int32)
+        sizes = onehot.sum(0)  # (H,) rows of each held expert
+        padded = (sizes + tile - 1) // tile * tile
+        rank = (jnp.cumsum(onehot, 0) * onehot).sum(1) - 1
+        # the most rows the groups can take, each group's last tile padded
+        M = -(-(T * min(K, H) + H * (tile - 1)) // tile) * tile
+        row = jnp.where(held, (jnp.cumsum(padded) - padded)[local % H] + rank, M)
+        token = jnp.full((M,), T, jnp.int32).at[row].set(
+            jnp.arange(T * K, dtype=jnp.int32) // K, mode="drop")
+        rows = jnp.concatenate([xf.astype(ct), jnp.zeros((1, D), ct)])[token]
+
+        def gmm(lhs, w):
+            return ops.grouped_matmul(lhs, w.astype(ct), padded, out_dtype=ct,
+                                      tile_rows=tile)
+
+        h = jax.nn.silu(gmm(rows, p["wg"]).astype(jnp.float32)).astype(ct) \
+            * gmm(rows, p["wu"])
+        out = gmm(h, p["wd"])  # (M, D); rows past the groups are undefined
+        back = out[jnp.minimum(row, M - 1)].reshape(T, K, D).astype(jnp.float32)
+        back = jnp.where(held.reshape(T, K, 1), back, 0.0)
+        y = (gates[..., None] * back).sum(axis=1).reshape(B, S, D)
+        if cfg.moe_shared_expert:
+            y = y + mlp_forward(cfg, p["shared"], x).astype(jnp.float32)
+        counters = jnp.stack([sizes.sum(), sizes.max()])
+        return y.astype(x.dtype), counters
+
+
 # ---------------------------------------------------------------------------
 # Embedding / unembedding
 # ---------------------------------------------------------------------------
@@ -548,7 +620,10 @@ def init_embed(cfg: ModelConfig, key) -> dict:
 
 
 def embed(cfg: ModelConfig, p: dict, tokens: jax.Array) -> jax.Array:
-    return jnp.take(p["embedding"], tokens, axis=0).astype(cdtype(cfg))
+    h = jnp.take(p["embedding"], tokens, axis=0).astype(cdtype(cfg))
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
+    return h
 
 
 def unembed(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
@@ -557,4 +632,7 @@ def unembed(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
         w = p["embedding"].astype(ct).T
     else:
         w = p["lm_head"].astype(ct)
-    return jnp.einsum("bsd,dv->bsv", x.astype(ct), w).astype(jnp.float32)
+    logits = jnp.einsum("bsd,dv->bsv", x.astype(ct), w).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits

@@ -83,6 +83,33 @@ def init_params(cfg: ModelConfig, key) -> Params:
 # ---------------------------------------------------------------------------
 
 
+def _residual(cfg: ModelConfig, y: jax.Array) -> jax.Array:
+    """A sublayer's output as it joins the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return y
+
+
+def _ffn(cfg: ModelConfig, spec: SublayerSpec, p: dict, x: jax.Array):
+    """The sublayer's FFN on its normed input: (y, aux, expert counters or
+    None)."""
+    if spec.ffn == "dense":
+        return L.mlp_forward(cfg, p, x), jnp.zeros((), jnp.float32), None
+    if cfg.moe_dropless:
+        y, cnt = L.moe_dropless_forward(cfg, p, x)
+        return y, jnp.zeros((), jnp.float32), cnt
+    y, aux = L.moe_forward(cfg, p, x)
+    return y, aux, None
+
+
+def _merge_counters(a, b):
+    """Expert counters over layers: rows summed, the busiest expert's rows
+    the largest. None where no layer has dropless experts."""
+    if b is None:
+        return a
+    return jnp.stack([a[0] + b[0], jnp.maximum(a[1], b[1])])
+
+
 def _apply_sublayer_full(
     cfg: ModelConfig,
     spec: SublayerSpec,
@@ -95,8 +122,10 @@ def _apply_sublayer_full(
     cache: Optional[dict],
     mode: str,  # 'train' | 'prefill'
 ):
-    """Full-sequence sublayer. Returns (h, new_cache_entry, aux)."""
+    """Full-sequence sublayer. Returns (h, new_cache_entry, aux, expert
+    counters or None)."""
     aux = jnp.zeros((), jnp.float32)
+    cnt = None
     new_cache = {}
     x = L.rms_norm(h, p["norm1"], cfg.rmsnorm_eps)
     if spec.mixer == "attn":
@@ -114,7 +143,7 @@ def _apply_sublayer_full(
             new_cache["conv"] = conv_tail.astype(cache["conv"].dtype)
         else:
             y = M.mamba_forward(cfg, p["mixer"], x)
-    h = h + y.astype(h.dtype)
+    h = h + _residual(cfg, y.astype(h.dtype))
 
     if "cross" in p and enc is not None:
         xc = L.rms_norm(h, p["norm_cross"], cfg.rmsnorm_eps)
@@ -124,16 +153,13 @@ def _apply_sublayer_full(
             new_cache["cv"] = cv.astype(cache["cv"].dtype)
         else:
             yc = L.cross_attention_forward(cfg, p["cross"], xc, enc)
-        h = h + yc.astype(h.dtype)
+        h = h + _residual(cfg, yc.astype(h.dtype))
 
     if spec.ffn != "none":
         x2 = L.rms_norm(h, p["norm2"], cfg.rmsnorm_eps)
-        if spec.ffn == "dense":
-            f = L.mlp_forward(cfg, p["ffn"], x2)
-        else:
-            f, aux = L.moe_forward(cfg, p["ffn"], x2)
-        h = h + f.astype(h.dtype)
-    return h, new_cache, aux
+        f, aux, cnt = _ffn(cfg, spec, p["ffn"], x2)
+        h = h + _residual(cfg, f.astype(h.dtype))
+    return h, new_cache, aux, cnt
 
 
 def _cross_with_kv(cfg, p, x, enc):
@@ -162,7 +188,7 @@ def _apply_sublayer_step(
     else:
         y, ns, ncv = M.mamba_decode(cfg, p["mixer"], x, cache["ssm"], cache["conv"])
         new_cache["ssm"], new_cache["conv"] = ns.astype(cache["ssm"].dtype), ncv
-    h = h + y.astype(h.dtype)
+    h = h + _residual(cfg, y.astype(h.dtype))
 
     if "cross" in p:
         xc = L.rms_norm(h, p["norm_cross"], cfg.rmsnorm_eps)
@@ -173,19 +199,19 @@ def _apply_sublayer_step(
             cache["cv"],
             jnp.full((h.shape[0],), cache["ck"].shape[1], jnp.int32),
         )
-        h = h + L._out_proj(cfg, p["cross"], out).astype(h.dtype)
+        h = h + _residual(cfg, L._out_proj(cfg, p["cross"], out).astype(h.dtype))
 
     if spec.ffn != "none":
         x2 = L.rms_norm(h, p["norm2"], cfg.rmsnorm_eps)
-        if spec.ffn == "dense":
-            f = L.mlp_forward(cfg, p["ffn"], x2)
+        if spec.ffn == "dense" or cfg.moe_dropless:
+            f, _, _ = _ffn(cfg, spec, p["ffn"], x2)
         else:
             B = x2.shape[0]
             f, aux = L.moe_forward(
                 cfg, p["ffn"], x2.reshape(1, B, -1), capacity_factor=4.0
             )
             f = f.reshape(B, 1, -1)
-        h = h + f.astype(h.dtype)
+        h = h + _residual(cfg, f.astype(h.dtype))
     return h, new_cache, aux
 
 
@@ -219,10 +245,12 @@ def _run_stack_full(
     cache_stack: Optional[dict] = None,
     mode: str = "train",
 ):
-    """Scan the period stack over a full sequence. Returns (h, new_cache, aux)."""
+    """Scan the period stack over a full sequence. Returns (h, new_cache,
+    aux, expert counters): the counters are int32 (2,) where the stack has
+    dropless experts (``_merge_counters``), else None."""
 
     def period_fn(carry, xs):
-        h, aux = carry
+        h, aux, cnt = carry
         if cache_stack is not None:
             pp, cc = xs
         else:
@@ -230,20 +258,23 @@ def _run_stack_full(
         new_cc = {}
         for i, spec in enumerate(specs):
             ci = cc[f"sub{i}"] if cc is not None else None
-            h, nci, a = _apply_sublayer_full(
+            h, nci, a, c = _apply_sublayer_full(
                 cfg, spec, pp[f"sub{i}"], h,
                 positions=positions, causal=causal, enc=enc, cache=ci, mode=mode,
             )
             aux = aux + a
+            cnt = _merge_counters(cnt, c)
             if cc is not None:
                 new_cc[f"sub{i}"] = {**ci, **nci}
         h = constrain(h, "residual")  # scan-carry layout (saved for backward)
-        return (h, aux), new_cc if cache_stack is not None else 0
+        return (h, aux, cnt), new_cc if cache_stack is not None else 0
 
     period_fn = _maybe_remat(cfg, period_fn)
     xs = (stack_params, cache_stack) if cache_stack is not None else stack_params
-    (h, aux), new_cache = lax.scan(period_fn, (h, jnp.zeros((), jnp.float32)), xs)
-    return h, (new_cache if cache_stack is not None else None), aux
+    cnt0 = jnp.zeros((2,), jnp.int32) if cfg.moe_dropless else None
+    (h, aux, cnt), new_cache = lax.scan(
+        period_fn, (h, jnp.zeros((), jnp.float32), cnt0), xs)
+    return h, (new_cache if cache_stack is not None else None), aux, cnt
 
 
 def _run_stack_step(cfg: ModelConfig, stack_params: dict, specs, h, cache_stack, *, positions):
@@ -276,14 +307,19 @@ def _encode(cfg: ModelConfig, params: Params, enc_embeds: jax.Array) -> jax.Arra
     pos = jnp.arange(S)[None, :]
     enc_spec = (SublayerSpec(mixer="attn", ffn="dense"),)
     h = enc_embeds.astype(L.cdtype(cfg))
-    h, _, _ = _run_stack_full(
+    h, _, _, _ = _run_stack_full(
         cfg, params["enc_layers"], enc_spec, h, positions=pos, causal=cfg.encoder_causal
     )
     return L.rms_norm(h, params["enc_final_norm"], cfg.rmsnorm_eps)
 
 
-def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]):
-    """Training/eval forward. Returns (logits (B,S,V) fp32, aux_loss)."""
+def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
+            *, counters: bool = False):
+    """Training/eval forward. Returns (logits (B,S,V) fp32, aux_loss), and
+    with ``counters`` the expert counters too: int32 (2,), the (token,
+    expert) rows the held experts computed summed over layers, and the rows
+    of the busiest held expert, the largest over layers (None without
+    dropless experts)."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     if cfg.mrope_sections:
@@ -296,13 +332,13 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]):
     if cfg.is_encoder_decoder:
         enc = _encode(cfg, params, batch["encoder_embeds"])
     h = L.embed(cfg, params["embed"], tokens)
-    h, _, aux = _run_stack_full(
+    h, _, aux, cnt = _run_stack_full(
         cfg, params["layers"], cfg.period_spec(), h,
         positions=positions, causal=cfg.causal, enc=enc,
     )
     h = L.rms_norm(h, params["final_norm"], cfg.rmsnorm_eps)
     logits = L.unembed(cfg, params["embed"], h)
-    return logits, aux
+    return (logits, aux, cnt) if counters else (logits, aux)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, enc_len: int = 0) -> Cache:
@@ -346,7 +382,7 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], cache
     if cfg.is_encoder_decoder:
         enc = _encode(cfg, params, batch["encoder_embeds"])
     h = L.embed(cfg, params["embed"], tokens)
-    h, cache, aux = _run_stack_full(
+    h, cache, aux, _ = _run_stack_full(
         cfg, params["layers"], cfg.period_spec(), h,
         positions=positions, causal=cfg.causal, enc=enc,
         cache_stack=cache, mode="prefill",

@@ -142,3 +142,30 @@ def test_ssd_step_equals_scan():
     y_step, _ = ssd_step_ref(state, x[:, S], dt[:, S], A, Bm[:, S], Cm[:, S])
     np.testing.assert_allclose(np.asarray(y_step), np.asarray(y_full[:, S]),
                                atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "sizes,M,K,N,tile",
+    [
+        ((128, 256, 128), 512, 64, 96, 128),   # every row in a group
+        ((128, 0, 64), 384, 64, 96, 64),       # an empty group, rows left over
+        ((32, 32, 0, 96), 256, 128, 256, 32),  # the dropless layer's small groups
+    ],
+)
+def test_grouped_matmul_sweep(dtype, sizes, M, K, N, tile):
+    """The megablox kernel in interpret mode against ``lax.ragged_dot``
+    over the rows its groups cover; rows past them are undefined."""
+    from repro.kernels.ops import grouped_matmul
+
+    ks = jax.random.split(KEY, 2)
+    lhs = jax.random.normal(ks[0], (M, K), dtype)
+    rhs = jax.random.normal(ks[1], (len(sizes), K, N), dtype)
+    gs = jnp.array(sizes, jnp.int32)
+    got, want = (grouped_matmul(lhs, rhs, gs, out_dtype=jnp.float32,
+                                tile_rows=tile, use_pallas=p)
+                 for p in ("interpret", False))
+    n = sum(sizes)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
+                               atol=tol * K ** 0.5, rtol=tol)

@@ -51,7 +51,8 @@ def test_smoke_train_step(arch):
 @pytest.mark.parametrize(
     "arch",
     ["qwen3-8b", "qwen2.5-3b", "mamba2-780m", "jamba-1.5-large-398b",
-     "olmoe-1b-7b", "whisper-small", "qwen2-vl-72b"],
+     "olmoe-1b-7b", "whisper-small", "qwen2-vl-72b",
+     "granite-4.0-h-small-ep4-rank0"],
 )
 def test_decode_matches_forward(arch):
     """prefill + token-by-token decode reproduces the full-sequence logits."""
@@ -94,7 +95,8 @@ def test_param_count_analytic_matches_actual(arch):
 def test_long_500k_applicability_rules():
     runs = {a for a in ALL_ARCHS
             if shape_applicable(ARCHS[a], SHAPES["long_500k"])[0]}
-    assert runs == {"mamba2-780m", "jamba-1.5-large-398b"}
+    assert runs == {"mamba2-780m", "jamba-1.5-large-398b", "granite-4.0-h-small",
+                    "granite-4.0-h-small-ep4-rank0"}
 
 
 def test_arch_configs_exact():
@@ -120,6 +122,15 @@ def test_arch_configs_exact():
     assert (c.num_layers, c.d_model, c.num_heads, c.num_kv_heads) == (36, 4096, 32, 8)
     c = ARCHS["phi4-mini-3.8b"]
     assert (c.num_layers, c.d_model, c.vocab_size) == (32, 3072, 200064)
+    c = ARCHS["granite-4.0-h-small"]
+    assert (c.num_layers, c.d_model, c.num_experts, c.experts_per_token,
+            c.moe_d_ff, c.moe_shared_d_ff, c.vocab_size) == \
+        (40, 4096, 72, 10, 768, 1536, 100352)
+    assert c.num_attn_layers == 4 and c.num_mamba_layers == 36
+    c = ARCHS["granite-4.0-h-small-ep4-rank0"]
+    assert (c.num_layers, c.num_experts, c.moe_held, c.expert_offset) == \
+        (10, 72, 18, 0)
+    assert [s.mixer for s in c.period_spec()].index("attn") == 5
     c = ARCHS["whisper-small"]
     assert (c.encoder_layers, c.num_layers, c.d_model, c.vocab_size) == \
         (12, 12, 768, 51865)

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.functions import model_config, served_logits
 from repro.kernels.decode_attention import decode_attention
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import init_params
@@ -94,6 +95,45 @@ def test_served_qwen2_5_3b_forward_fits_one_v5e(one_chip):
     weights = sum(x.size * x.dtype.itemsize
                   for x in jax.tree_util.tree_leaves(params))
     assert mem.argument_size_in_bytes >= weights > 5.7 * 2**30
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)])
+def test_grouped_matmul_compiles_at_granite_4_0_h_small_widths(one_chip, k, n):
+    """The held experts' gate/up (d 4096 -> 768) and down products over
+    the dropless layer's buffer: 18 held experts, 256 tokens x top-10."""
+    from functools import partial
+
+    from repro.models.layers import EXPERT_ROW_TILE
+
+    m = 256 * 10 + 18 * EXPERT_ROW_TILE
+    _kernel_compiles(partial(grouped_matmul, out_dtype=jnp.bfloat16,
+                             tile_rows=EXPERT_ROW_TILE),
+                     _shape(one_chip, (m, k)), _shape(one_chip, (18, k, n)),
+                     _shape(one_chip, (18,), jnp.int32))
+
+
+def test_served_granite_rank0_forward_fits_one_v5e(one_chip, monkeypatch):
+    """The one-chip share the benchmark serves, 1 x 256 tokens, with the
+    Pallas grouped products the chip takes (the CPU backend here would
+    take ``ragged_dot``)."""
+    import repro.kernels.ops as ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = model_config("granite-4.0-h-small-ep4-rank0", full_width=True)
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    params = jax.tree_util.tree_map(
+        lambda x: _shape(one_chip, x.shape, x.dtype), params)
+    compiled = jax.jit(served_logits(cfg)).lower(
+        params, _shape(one_chip, (1, 256), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3 * cfg.num_layers
+    mem = compiled.memory_analysis()
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert mem.argument_size_in_bytes >= weights > 6.5e9
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
